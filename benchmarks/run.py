@@ -7,6 +7,12 @@ Flags:
   --quick        tiny shape set (CI smoke; seconds, not minutes)
   --json PATH    also dump the rows as a JSON artifact
   --only NAME    run a single benchmark by substring match
+
+The persistent compile cache is on (``repro.launch.compile_cache``), so a
+repeat run reads cached compiles: the "cold" serving rows (legacy cold
+TTFT, ``serve_paged_long`` cold tok/s and TTFT) measure a cold compile
+only on the first run into an empty cache.  Point
+``JAX_COMPILATION_CACHE_DIR`` at an empty directory to time them cold.
 """
 from __future__ import annotations
 
@@ -24,6 +30,9 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="substring filter on benchmark names")
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks.paper_figures import (bench_fig4_speedup, bench_fig5_edp,
                                           bench_fig6_redas,

@@ -15,7 +15,7 @@ import dataclasses
 import json
 from typing import Dict
 
-from repro.hw.specs import ChipSpec, TPU_V5E
+from repro.hw.specs import chip_spec
 
 
 @dataclasses.dataclass
@@ -55,8 +55,11 @@ def _bytes_from_cost(cost: dict) -> Dict[str, float]:
 
 def build_report(*, arch: str, cell: str, mesh_name: str, chips: int,
                  cost: dict, hlo_text: str, model_flops: float,
-                 tokens_per_step: float, spec: ChipSpec = TPU_V5E,
+                 tokens_per_step: float, device_kind: str,
                  axis_group_hint: int = 16) -> RooflineReport:
+    """``device_kind`` names the chip whose peaks bound the terms
+    (:func:`repro.hw.specs.chip_spec` raises for an unknown one)."""
+    spec = chip_spec(device_kind)
     # Trip-count-aware walker (repro.analysis.hlo_cost): XLA's own
     # cost_analysis() counts while-loop bodies once, so scan-over-layers
     # programs under-report by the trip count.  The raw cost_analysis

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as compat_shard_map
-
 PyTree = Any
 
 
@@ -78,7 +76,7 @@ def pipeline_apply(stage_fn: Callable, stage_params: PyTree,
             jnp.where(stage == n_stages - 1, outs, 0), axis)
         return outs
 
-    return compat_shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
         check_vma=False)(stage_params, x_micro)

@@ -22,6 +22,7 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.kernels.paged_attn import kv_pool_layout
 from repro.models.common import Sharder
 
 PyTree = Any
@@ -210,6 +211,15 @@ _POOL_LEAVES = ("pk", "pv", "pk_s", "pv_s",   # global page pool
                 "ck", "cv")                    # enc-dec cross pool
 
 
+def _pool_axes(layout: str, model) -> Tuple:
+    """Mesh axes of one layer's paged pool ``(pages, psz, Hkv, hd)`` for
+    a :func:`~repro.kernels.paged_attn.kv_pool_layout`; the page axis is
+    never split."""
+    return {"heads": (None, None, model, None),
+            "replicated": (None, None, None, None)}.get(
+                layout, (None, model, None, None))
+
+
 def cache_specs(cache_shapes: PyTree, cfg, mesh: Mesh, *,
                 batch_axes=None) -> PyTree:
     """PartitionSpec pytree for serving KV storage on a TP/DP mesh.
@@ -223,8 +233,9 @@ def cache_specs(cache_shapes: PyTree, cfg, mesh: Mesh, *,
       paged pools ``(L, pages+1, psz, Hkv, hd|1)``: the page axis is
       **never** sharded (the page tables index physical pages globally,
       so every shard must see every page row); K/V heads go
-      tensor-parallel over ``model`` when divisible, else the page
-      interior seq-shards.
+      tensor-parallel over ``model`` when divisible, a single KV head
+      (multi-query attention) is replicated, else the page interior
+      seq-shards (:func:`repro.kernels.paged_attn.kv_pool_layout`).
     * ``k``/``v`` (+ scales) — dense slot cache ``(L, B, cap, Hkv,
       hd|1)``: batch over ``batch_axes`` and heads over ``model`` when
       divisible, else the sequence axis shards (long-context fallback).
@@ -249,6 +260,7 @@ def cache_specs(cache_shapes: PyTree, cfg, mesh: Mesh, *,
     ms = _axes_size(mesh, M)
     head_ok = (ms > 1 and cfg.n_heads % ms == 0
                and cfg.n_kv_heads % ms == 0)
+    pool_layout = kv_pool_layout(cfg.n_heads, cfg.n_kv_heads, ms)
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(cache_shapes)
     specs = []
@@ -261,12 +273,7 @@ def cache_specs(cache_shapes: PyTree, cfg, mesh: Mesh, *,
         shape = tuple(leaf.shape)
         nd = len(shape)
         if name in _POOL_LEAVES and nd == 5:
-            if head_ok:
-                spec = _canon((None, None, None, _fit(mesh, shape[3], M),
-                               None))
-            else:
-                spec = _canon((None, None, _fit(mesh, shape[2], M), None,
-                               None))
+            spec = _spec(mesh, shape, None, *_pool_axes(pool_layout, M))
         elif name == "state" and nd == 5:
             # WKV state — dense (L, B, H, hd, hd) or paged slab
             # (L, slots, H, hd, hd): heads live on axis 2 (not axis 3
@@ -346,6 +353,10 @@ class MeshSharder(Sharder):
             if head_ok:
                 return self._c(x, B, M, None, None)
             return self._c(x, B, None, None, M)   # seq-sharded softmax
+        if role == "kv_pool":           # (pages, psz, Hkv, hd)
+            return self._c(x, *_pool_axes(kv_pool_layout(
+                self.cfg.n_heads, self.cfg.n_kv_heads, self.mesh.shape[M]),
+                M))
         if role == "kv_cache":          # (B, cap, Hkv, hd)
             if head_ok:
                 return self._c(x, B, None, M, None)

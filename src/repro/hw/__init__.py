@@ -1,4 +1,5 @@
-from repro.hw.specs import (AsicSpec, ChipSpec, SISA_ASIC, TPU_BASELINE_ASIC,
-                            TPU_V5E)
+from repro.hw.specs import (AsicSpec, chip_spec, ChipSpec, SISA_ASIC,
+                            TPU_BASELINE_ASIC, TPU_V5E)
 
-__all__ = ["TPU_V5E", "SISA_ASIC", "TPU_BASELINE_ASIC", "ChipSpec", "AsicSpec"]
+__all__ = ["TPU_V5E", "chip_spec", "SISA_ASIC", "TPU_BASELINE_ASIC",
+           "ChipSpec", "AsicSpec"]

@@ -2,8 +2,9 @@
 
 Two distinct targets live here and must not be conflated:
 
-* ``TPUv5e`` — the *runtime* target for the JAX/Pallas layers and the
-  roofline analysis (197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s ICI link).
+* ``TPU_V5E`` — the *runtime* target for the JAX/Pallas layers and the
+  roofline analysis (197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s ICI link),
+  looked up by device kind through :func:`chip_spec`.
 * ``SISA_ASIC`` — the paper's 28 nm 1 GHz accelerator instance (Table 3),
   used only by the cycle/energy simulator in ``repro.core``.
 """
@@ -26,6 +27,8 @@ class ChipSpec:
     mxu_dim: int                # systolic array dimension
 
 
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links).
 TPU_V5E = ChipSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
@@ -36,6 +39,20 @@ TPU_V5E = ChipSpec(
     vmem_bytes=128 * 1024**2,
     mxu_dim=128,
 )
+
+#: The one peaks table, keyed by ``jax.Device.device_kind``.
+CHIP_SPECS = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """Peaks of the chip JAX reports as ``device_kind``; a chip with no
+    entry in :data:`CHIP_SPECS` is an error, never a default."""
+    try:
+        return CHIP_SPECS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks recorded for device kind "
+                       f"{device_kind!r}; known: {sorted(CHIP_SPECS)}"
+                       ) from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -56,7 +56,6 @@ from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import CompilerParams
 from repro.kernels.runtime import resolve_interpret
 from repro.kernels.sisa_gemm import choose_block_config
 
@@ -258,7 +257,7 @@ def _coexec_call(plan: CoexecPlan, a_flat: jax.Array, b_stack: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((plan.m_flat, plan.np_pad),
                                        a_flat.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=resolve_interpret(interpret),

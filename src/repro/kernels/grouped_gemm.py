@@ -83,7 +83,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
-from repro.compat import CompilerParams
 from repro.kernels.runtime import resolve_interpret
 from repro.kernels.sisa_gemm import choose_block_config
 
@@ -235,7 +234,7 @@ def _flat_forward(x, w, starts, sizes, gids, *, bm, m_hint, interpret):
         functools.partial(_flat_fwd_kernel, n_k=n_k, bm=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, fp), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=resolve_interpret(interpret),
@@ -273,7 +272,7 @@ def _flat_dw(x, dy, starts, sizes, gids, n_groups, *, bm, m_hint, interpret):
         functools.partial(_flat_dw_kernel, bm=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_groups, dp, fp), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=resolve_interpret(interpret),

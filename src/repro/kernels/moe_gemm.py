@@ -14,7 +14,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
-from repro.compat import CompilerParams
 from repro.kernels.runtime import resolve_interpret
 from repro.kernels.sisa_gemm import choose_block_config
 
@@ -63,7 +62,7 @@ def moe_grouped_gemm(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((1, bc, bf), lambda ee, i, j, kk: (ee, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, cp, fp), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

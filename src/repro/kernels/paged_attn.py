@@ -44,7 +44,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
-from repro.compat import CompilerParams
 from repro.kernels.runtime import resolve_interpret
 
 NEG_INF = jnp.finfo(jnp.float32).min
@@ -179,7 +178,7 @@ def _paged_attention_pallas(q, pk, pv, table, pos, pk_scale, pv_scale,
                           n_rep=n_rep, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_heads, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=resolve_interpret(interpret),
@@ -268,6 +267,31 @@ def paged_attention(q, pk, pv, table, pos, *,
     raise ValueError(f"paged_attention cannot dispatch impl={impl!r}")
 
 
+def kv_pool_layout(n_heads: int, n_kv_heads: int, ms: int) -> str:
+    """How a paged KV pool spreads over a ``model`` axis of size ``ms``.
+
+    * ``"heads"`` — both head counts divide ``ms``: each shard holds its
+      query heads and their KV heads, so the GQA group ratio survives.
+    * ``"replicated"`` — multi-query attention (one KV head) with the
+      query heads dividing ``ms``: query heads split, and every shard
+      holds the whole single-head pool (``2 * head_dim`` elements per
+      token and layer).
+    * ``"seq"`` — anything else (or no model axis): the page interior
+      splits and attention runs unsharded under GSPMD.
+
+    The one rule behind :func:`paged_attention_sharded`,
+    ``distributed.sharding.cache_specs`` and the ``kv_pool`` activation
+    role, so pool shardings never disagree between allocation and the
+    decode step.
+    """
+    if ms > 1 and n_heads % ms == 0:
+        if n_kv_heads % ms == 0:
+            return "heads"
+        if n_kv_heads == 1:
+            return "replicated"
+    return "seq"
+
+
 def paged_attention_sharded(q, pk, pv, table, pos, *, mesh,
                             model_axis: str = "model",
                             pk_scale=None, pv_scale=None,
@@ -277,35 +301,39 @@ def paged_attention_sharded(q, pk, pv, table, pos, *, mesh,
     Heads are embarrassingly parallel in the online-softmax recurrence,
     so each ``model``-axis shard runs the *unmodified* kernel (fused
     Pallas or its XLA twin — whichever ``impl`` resolves to) over its
-    own slice of the query heads and the page pool's KV heads:
+    own slice of the query heads, against the pool as
+    :func:`kv_pool_layout` places it:
 
     * ``q``: ``P(None, model, None)`` — query heads split;
     * ``pk``/``pv`` (+ scale planes): ``P(None, None, model, None)`` —
-      KV heads split, the *page* axis replicated (every shard sees every
-      physical page; the table indexes pages globally);
+      KV heads split — or replicated for multi-query attention, where
+      every shard's query heads share the one KV head; the *page* axis
+      is never split (the table indexes pages globally);
     * ``table``/``pos``: replicated.
 
-    GQA survives sharding because ``n_heads % ms == 0`` and
-    ``n_kv_heads % ms == 0`` keep the per-shard group ratio intact.
-    Falls back to the single-device call when the mesh's ``model`` axis
-    is absent, size 1, or does not divide either head count — the same
-    divisibility-guarded degradation as ``cache_specs``.
+    Without a model axis the plain call runs.  For a ``"seq"`` layout on
+    a real model axis the XLA twin runs unsharded under GSPMD, and the
+    Pallas kernel — a custom call GSPMD cannot partition — raises
+    instead of being fed an all-gathered pool on every step.
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map as compat_shard_map
-
     ms = mesh.shape[model_axis] if model_axis in mesh.axis_names else 1
-    n_heads, n_kv = q.shape[1], pk.shape[2]
-    if ms <= 1 or n_heads % ms or n_kv % ms:
-        return paged_attention(q, pk, pv, table, pos,
-                               pk_scale=pk_scale, pv_scale=pv_scale,
-                               impl=impl)
     # Resolve the backend *outside* shard_map so a context-manager
     # override at trace time is honored inside every shard.
     impl = impl or resolve_paged_attn_backend()
+    layout = kv_pool_layout(q.shape[1], pk.shape[2], ms)
+    if layout == "seq":
+        if ms > 1 and impl == "pallas":
+            raise ValueError(
+                f"paged_attn: {q.shape[1]} query / {pk.shape[2]} KV heads "
+                f"do not shard over a {ms}-way model axis; the Pallas "
+                "kernel cannot run on a page-split pool")
+        return paged_attention(q, pk, pv, table, pos,
+                               pk_scale=pk_scale, pv_scale=pv_scale,
+                               impl=impl)
     head = P(None, model_axis, None)
-    pool = P(None, None, model_axis, None)
+    pool = P(None, None, model_axis, None) if layout == "heads" else P()
     args = [q, pk, pv, table, pos]
     in_specs = [head, pool, pool, P(None, None), P(None)]
     if pk_scale is not None:
@@ -317,9 +345,8 @@ def paged_attention_sharded(q, pk, pv, table, pos, *, mesh,
         return paged_attention(q_, pk_, pv_, tbl_, pos_,
                                pk_scale=ks, pv_scale=vs, impl=impl)
 
-    return compat_shard_map(shard_fn, mesh=mesh,
-                            in_specs=tuple(in_specs), out_specs=head,
-                            check_vma=False)(*args)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=head, check_vma=False)(*args)
 
 
 def quantize_page_pool(x) -> Tuple[jax.Array, jax.Array]:

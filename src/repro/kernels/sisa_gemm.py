@@ -29,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
-from repro.compat import CompilerParams
 from repro.kernels.runtime import resolve_interpret
 
 LANE = 128
@@ -139,7 +138,7 @@ def sisa_gemm_splitk(a: jax.Array, b: jax.Array, cfg: BlockConfig,
         out_specs=pl.BlockSpec((1, cfg.bm, cfg.bn),
                                lambda kk, i, j: (kk, i, j)),
         out_shape=jax.ShapeDtypeStruct((n_k, m, n), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=resolve_interpret(interpret),
@@ -173,7 +172,7 @@ def sisa_gemm(a: jax.Array, b: jax.Array, cfg: BlockConfig,
         out_specs=pl.BlockSpec((cfg.bm, cfg.bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((cfg.bm, cfg.bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=resolve_interpret(interpret),

@@ -1,9 +1,12 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-MUST set XLA_FLAGS before any other import (jax locks the device count on
-first init) — hence the first two lines.
+MUST pin the CPU platform and set XLA_FLAGS before any other import (jax
+locks the platform and device count on first init) — hence the first
+lines.  The 512 devices are host CPU devices; the dry-run never takes an
+accelerator, even on a machine that has one.
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))
@@ -17,7 +20,6 @@ from typing import Dict
 import jax
 
 from repro.analysis.roofline import build_report, model_flops_for
-from repro.compat import cost_analysis as compat_cost_analysis
 from repro.configs import (ASSIGNED_ARCHS, cell_applicable, get_config,
                            SHAPE_CELLS, smoke_config)
 from repro.distributed.sharding import (batch_specs, opt_state_specs,
@@ -27,6 +29,10 @@ from repro.launch.mesh import make_production_mesh
 from repro.serve.serve_step import (cache_specs, make_decode_step,
                                     make_prefill_step)
 from repro.train.train_step import make_train_step
+
+# The chip whose peaks the roofline terms use: the production mesh
+# models a v5e pod on host devices.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "artifacts", "dryrun")
@@ -177,7 +183,7 @@ def lower_cell(arch: str, cell_name: str, mesh_kind: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    cost = compat_cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     mem = _mem_analysis(compiled)
     hlo = compiled.as_text()
     report = build_report(
@@ -185,7 +191,7 @@ def lower_cell(arch: str, cell_name: str, mesh_kind: str,
         cost=cost, hlo_text=hlo,
         model_flops=model_flops_for(cfg, cell),
         tokens_per_step=cell.global_batch * cell.seq_len,
-        axis_group_hint=16)
+        device_kind=TARGET_DEVICE_KIND, axis_group_hint=16)
 
     artifact = {
         "arch": arch, "cell": cell_name, "mesh": mesh_kind,

@@ -21,8 +21,9 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
-            "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-            "(repro.launch.dryrun does this automatically)")
+            "run on the CPU platform under "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=512 "
+            "(repro.launch.dryrun sets both automatically)")
     import numpy as np
     return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
 

@@ -6,12 +6,14 @@
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serve import make_engine, Request
 
@@ -28,9 +30,11 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     print(f"[launch.serve] arch={cfg.name} devices={jax.device_count()}")
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
+    params = jax.jit(functools.partial(init_params, cfg))(
+        jax.random.PRNGKey(args.seed))
     eng = make_engine(cfg, params, kind=args.engine, max_slots=8,
                       max_seq=args.max_seq)
     rng = np.random.default_rng(args.seed)

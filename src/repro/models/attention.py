@@ -442,8 +442,8 @@ def paged_attn_decode_step(p, x: Array, cache: Dict[str, Array],
         pk = cache["pk"].at[phys, off].set(k[:, 0])
         pv = cache["pv"].at[phys, off].set(v[:, 0])
         pk_s = pv_s = None
-    pk = sharder.constrain(pk, "kv_cache")
-    pv = sharder.constrain(pv, "kv_cache")
+    pk = sharder.constrain(pk, "kv_pool")
+    pv = sharder.constrain(pv, "kv_pool")
     new_cache = {"pk": pk, "pv": pv}
     if quant:
         new_cache.update({"pk_s": pk_s, "pv_s": pv_s})
@@ -522,8 +522,8 @@ def paged_local_attn_decode_step(p, x: Array, cache: Dict[str, Array],
     off = pos % psz
     lk = cache["lk"].at[phys, off].set(k[:, 0])
     lv = cache["lv"].at[phys, off].set(v[:, 0])
-    lk = sharder.constrain(lk, "kv_cache")
-    lv = sharder.constrain(lv, "kv_cache")
+    lk = sharder.constrain(lk, "kv_pool")
+    lv = sharder.constrain(lv, "kv_pool")
     new_cache = {"lk": lk, "lv": lv}
     # Logical ring cell j holds position pos - ((pos - j) mod window_cap);
     # gather it back through the ring table (same cell order and validity

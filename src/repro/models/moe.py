@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as compat_shard_map
 from repro.models.common import activation, Array, dense_init
 
 
@@ -359,7 +358,7 @@ def moe_apply(p, x: Array, cfg, *, mesh=None,
             return y, jax.lax.pmean(aux, all_axes)
         out_specs = (bspec, P())
 
-    y, aux = compat_shard_map(
+    y, aux = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=out_specs, check_vma=False)(*args)
     return y, aux

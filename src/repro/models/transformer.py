@@ -63,13 +63,22 @@ def _stacked_group_init(key, pattern: Tuple[str, ...], n_repeats: int,
         return {f"b{i}": _block_init(kk[i], kind, cfg, dtype,
                                      with_cross=with_cross)
                 for i, kind in enumerate(pattern)}
-    reps = jax.random.split(key, n_repeats)
-    layers = [one(k) for k in reps]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+    # vmap draws every leaf already stacked: the values of stacking the
+    # per-layer inits, without a list of layers alive beside the stack.
+    return jax.vmap(one)(jax.random.split(key, n_repeats))
 
 
 def init_params(cfg: ModelConfig, key: jax.Array,
                 dtype_override: Optional[str] = None) -> PyTree:
+    """Random parameters for ``cfg`` drawn from ``key``.
+
+    At published widths call it as one compiled program,
+    ``jax.jit(partial(init_params, cfg), out_shardings=...)(key)``: each
+    weight is then drawn, scaled and cast inside XLA, so the device holds
+    only the final weights (eagerly, a float32 draw of each stacked leaf
+    stays alive beside them), and ``out_shardings`` places every leaf
+    straight onto its shards.  The values are those of the eager call.
+    """
     dtype = jnp.dtype(dtype_override or cfg.param_dtype)
     ks = jax.random.split(key, 8)
     params: Dict[str, PyTree] = {
@@ -352,8 +361,14 @@ def forward_prefill(params, cfg: ModelConfig, batch: Dict[str, Array], *,
     serves every prompt length in the bucket (causal masking makes
     trailing pad tokens invisible to it).  The vector form is the
     coalesced multi-prompt prefill: each batch row carries its own
-    last-token position, one gather instead of a shared slice.
+    last-token position, one gather instead of a shared slice.  A
+    ``(B, K)`` matrix returns ``(B, K, vocab)`` logits at K positions
+    per row — a teacher-forced read of one causal forward — with each
+    row's cache laid at its last column.
     """
+    last_index = logits_index
+    if logits_index is not None and jnp.ndim(logits_index) == 2:
+        last_index = logits_index[:, -1]
     enc_out = None
     if cfg.enc_dec:
         enc_out = _encode(params, cfg, batch, sharder=sharder, remat="none",
@@ -374,14 +389,15 @@ def forward_prefill(params, cfg: ModelConfig, batch: Dict[str, Array], *,
                                       cap_seq, sharder=sharder,
                                       enc_out=enc_out, mesh=mesh,
                                       batch_axes=batch_axes,
-                                      last_index=logits_index)
+                                      last_index=last_index)
                 cache[f"b{i}"] = c
             return x, cache
         x, cache = jax.lax.scan(body, x, gp)
         caches.append(cache)
     x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     if logits_index is not None and jnp.ndim(logits_index) >= 1:
-        idx = logits_index.astype(jnp.int32)[:, None, None]
+        idx = logits_index.astype(jnp.int32)
+        idx = idx[:, :, None] if idx.ndim == 2 else idx[:, None, None]
         x_last = jnp.take_along_axis(x, idx, axis=1)
     elif logits_index is not None:
         x_last = jax.lax.dynamic_slice_in_dim(x, logits_index, 1, axis=1)

@@ -1239,6 +1239,14 @@ class PagedServeEngine(SlotServeEngine):
             budget, rung=rung)
         return toks, pos, budget, out
 
+    def window_program_text(self, rung: int) -> str:
+        """Compiled HLO text of the decode window at batch shape ``rung``
+        over the current params, pools and page tables."""
+        z = jnp.zeros(rung, jnp.int32)
+        return self._window_fn.lower(
+            self.params, self.cache.pools, self.cache.tables(), z, z, z,
+            rung=rung).compile().as_text()
+
     def _build_window_fn(self):
         decode_fn = self.decode_fn
         vocab = self.cfg.vocab_size

@@ -5,7 +5,8 @@ import pytest
 
 from repro.analysis.hlo_cost import analyze, split_computations
 from repro.analysis.hlo_utils import collective_bytes
-from repro.compat import cost_analysis
+from repro.analysis.roofline import build_report
+from repro.hw.specs import chip_spec, TPU_V5E
 
 X = jax.ShapeDtypeStruct((256, 256), jnp.float32)
 
@@ -24,8 +25,8 @@ def test_xla_costs_count_loop_bodies_once():
         out, _ = jax.lax.scan(lambda c, _: (c @ c, None), y, None, length=10)
         return out
 
-    f1 = cost_analysis(jax.jit(one).lower(X).compile())["flops"]
-    f10 = cost_analysis(jax.jit(ten).lower(X).compile())["flops"]
+    f1 = jax.jit(one).lower(X).compile().cost_analysis()["flops"]
+    f10 = jax.jit(ten).lower(X).compile().cost_analysis()["flops"]
     assert f1 == f10        # the XLA behavior our walker corrects
 
 
@@ -88,3 +89,22 @@ def test_collective_parse_explicit_groups():
 def test_split_computations_finds_entry():
     comps = split_computations(_hlo(lambda y: y @ y, X))
     assert any(c.is_entry for c in comps.values())
+
+
+def test_chip_peaks_by_device_kind():
+    assert chip_spec("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="no peaks recorded"):
+        chip_spec("cpu")
+
+
+def test_roofline_refuses_unknown_device():
+    """The roofline takes its peaks from the device kind; a device with
+    no entry is an error, never a silent v5e default."""
+    hlo = _hlo(lambda y: y @ y, X)
+    kw = dict(arch="a", cell="c", mesh_name="m", chips=1,
+              cost={"flops": 1.0}, hlo_text=hlo, model_flops=1.0,
+              tokens_per_step=1.0)
+    rep = build_report(device_kind="TPU v5 lite", **kw)
+    assert rep.compute_s == pytest.approx(rep.flops_per_chip / 197e12)
+    with pytest.raises(KeyError):
+        build_report(device_kind="cpu", **kw)

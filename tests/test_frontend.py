@@ -95,9 +95,13 @@ class TestLifecycle:
         prompts = _workload(cfg, [6, 6, 11], seed=5)
         streams = {i: [] for i in range(3)}
         order_ok = {}
+        # A token can arrive before the last submit returns; the callback
+        # reads `handles`, so it waits until the list exists.
+        submitted = threading.Event()
 
         def cb(rid):
             def _cb(tok):
+                submitted.wait(timeout=60)
                 if rid == 2:
                     raise RuntimeError("user callback exploded")
                 streams[rid].append(tok)
@@ -107,6 +111,7 @@ class TestLifecycle:
         fe = ServeFrontend(_make(setup))
         handles = [fe.submit(p, 5, on_token=cb(i))
                    for i, p in enumerate(prompts)]
+        submitted.set()
         done = {c.rid: c for c in fe.drain(timeout=120)}
         fe.shutdown()
         for rid in (0, 1):

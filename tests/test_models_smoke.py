@@ -4,6 +4,8 @@ For each assigned arch: one forward/train step asserting output shapes and
 no NaNs, plus a prefill->decode consistency check (the decode step at
 position S must reproduce the full-sequence forward's next-token logits).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -206,3 +208,47 @@ def test_full_config_structure(arch):
     for cell in SHAPE_CELLS.values():
         ok, why = cell_applicable(cfg, cell)
         assert ok or why
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "whisper-base"])
+def test_jitted_init_params_matches_eager(arch):
+    """The compiled init draws the same weights from the same key as the
+    eager one, and the stacked layer axis holds each layer's own init."""
+    from repro.models.transformer import _block_init
+    cfg = smoke_config(arch)
+    key = jax.random.PRNGKey(4)
+    eager = init_params(cfg, key)
+    jitted = jax.jit(functools.partial(init_params, cfg))(key)
+    for a, b in zip(jax.tree.leaves(eager), jax.tree.leaves(jitted)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-7)
+    # Layer j of group 0 is _block_init under the j-th split key — the
+    # values a per-layer loop followed by a stack produces.
+    (pattern, reps), = cfg.layer_groups()[:1]
+    gkey = jax.random.split(jax.random.split(key, 8)[1], 8)[0]
+    for j, lk in enumerate(jax.random.split(gkey, reps)):
+        kk = jax.random.split(lk, len(pattern))
+        one = _block_init(kk[0], pattern[0], cfg, jnp.dtype(cfg.param_dtype),
+                          with_cross=cfg.enc_dec)
+        for a, b in zip(jax.tree.leaves(one),
+                        jax.tree.leaves(eager["groups"][0]["b0"])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b[j]))
+
+
+def test_prefill_logits_at_many_positions():
+    """A (B, K) logits_index returns the logits of K positions per row —
+    each equal to the scalar-index prefill at that position."""
+    cfg = smoke_config("yi-6b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (1, 24), 0,
+                              cfg.vocab_size)
+    idx = jnp.asarray([[3, 10, 17, 23]], jnp.int32)
+    many, _ = forward_prefill(params, cfg, {"tokens": toks},
+                              logits_index=idx)
+    assert many.shape[:2] == (1, 4)
+    for k, i in enumerate(np.asarray(idx[0])):
+        one, _ = forward_prefill(params, cfg, {"tokens": toks},
+                                 logits_index=jnp.int32(i))
+        np.testing.assert_allclose(np.asarray(many[0, k]),
+                                   np.asarray(one[0, 0]), rtol=1e-5,
+                                   atol=1e-5)

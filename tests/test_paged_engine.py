@@ -404,6 +404,20 @@ class TestPagedCompileStability:
         assert eng.stats["decode_compiles"] == eng._window_traces
         assert eng.stats["decode_compiles"] >= 1
 
+    def test_window_program_text_leaves_engine_untouched(self, setup):
+        """The compiled decode window's text is readable at any rung
+        without running it: the pools are not donated and a later serve
+        emits the same tokens."""
+        cfg, params = setup
+        eng = make_engine(cfg, params, kind="paged", max_slots=2,
+                          max_seq=64, window=2, page_size=8)
+        prompts = _prompts([5, 9], cfg.vocab_size)
+        want = _run(eng, prompts, [3, 3])
+        eng.reset()
+        text = eng.window_program_text(eng.rungs[-1])
+        assert "ENTRY" in text and "while" in text   # the T-step scan
+        assert _run(eng, prompts, [3, 3]) == want
+
     def test_prefill_compiles_once_per_page_count(self, setup):
         """Paged prompts bucket to page multiples: one prefill
         compilation per ceil(len/page) value, not per length."""

@@ -145,6 +145,25 @@ class TestCacheSpecs:
                     assert specs["dense"]["k"][3] == "model", (name, shape)
                     assert specs["pool"]["pk"][3] == "model", (name, shape)
 
+    def test_pool_layout_rule(self):
+        """One rule places every paged pool: KV heads split when both
+        head counts divide the model axis, a single KV head (MQA) is
+        replicated while query heads split, else the page interior
+        splits — and cache_specs follows it."""
+        from repro.kernels.paged_attn import kv_pool_layout
+        assert kv_pool_layout(32, 4, 4) == "heads"
+        assert kv_pool_layout(48, 1, 4) == "replicated"
+        assert kv_pool_layout(14, 2, 4) == "seq"
+        assert kv_pool_layout(48, 1, 1) == "seq"
+        assert kv_pool_layout(4, 1, 8) == "seq"
+        for name in ("granite-20b", "gemma3-1b"):
+            cfg = all_configs()[name]
+            mesh = FakeMesh({"data": 1, "model": 4}, ("data", "model"))
+            specs = cache_specs(_cache_trees(cfg), cfg, mesh, batch_axes=())
+            for leaf in ("pk", "pv", "pk_s", "pv_s"):
+                assert specs["pool"][leaf] == P(), (name, leaf)
+            assert specs["dense"]["k"][2] == "model"   # dense: seq-split
+
     def test_slot_dim_never_data_sharded_for_serving(self):
         """batch_axes=() (what the engines pass — the leading cache dim
         is a logical slot index) must keep 'data' out of every spec."""
@@ -340,6 +359,25 @@ def test_paged_attention_sharded_matches_plain(setup):
     # head-indivisible mesh: transparent fallback to the plain call
     got8 = paged_attention_sharded(q, pk, pv, table, pos, mesh=_mesh((1, 8)))
     np.testing.assert_allclose(np.asarray(got8), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@needs_mesh
+def test_mqa_paged_attention_sharded_matches_plain():
+    """Multi-query attention on a 4-way model axis: query heads split,
+    the one-KV-head pool replicated, same result as the plain call."""
+    from repro.kernels import paged_attention, paged_attention_sharded
+    rng = np.random.default_rng(6)
+    B, H, hd, npages, maxp = 4, 8, 8, 13, 4
+    q = jnp.asarray(rng.normal(size=(B, H, hd)).astype(np.float32))
+    pk, pv = (jnp.asarray(rng.normal(size=(npages + 1, PSZ, 1, hd))
+                          .astype(np.float32)) for _ in range(2))
+    table = jnp.asarray(np.stack([
+        rng.permutation(npages)[:maxp] + 1 for _ in range(B)]), jnp.int32)
+    pos = jnp.asarray(rng.integers(1, maxp * PSZ, size=(B,)), jnp.int32)
+    want = paged_attention(q, pk, pv, table, pos)
+    got = paged_attention_sharded(q, pk, pv, table, pos, mesh=_mesh((2, 4)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
 
