@@ -254,6 +254,8 @@ def validate_stats(stats: Dict[str, Any]) -> None:
 
 
 def now() -> float:
-    """Wall-clock source for arrival/first-token/finish stamps (one
-    definition so tests can monkeypatch time consistently)."""
-    return time.time()
+    """The one clock of every serving stamp and deadline: arrival,
+    admission, first token, finish (``time.perf_counter``, the clock the
+    chip benchmark times requests by).  Stamps are seconds on it; only
+    their differences mean anything."""
+    return time.perf_counter()

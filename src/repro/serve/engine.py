@@ -44,7 +44,6 @@ from __future__ import annotations
 from collections import deque
 import dataclasses
 import functools
-import time
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -55,6 +54,8 @@ from repro.configs.base import ModelConfig
 from repro.core import (coexec_tile_sequence, GemmRequest, packed_speedup,
                         requests_from_workload, simulate_workload, SISA_128)
 from repro.core.workloads import GemmLayer, LLMWorkload
+from repro.serve.api import now
+from repro.serve.spans import span
 
 SLAB_LADDER = (1, 2, 4, 8, 16, 32, 64, 128)
 
@@ -67,6 +68,9 @@ class Request:
     arrived: float = 0.0
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # Stamps on ``repro.serve.api.now``: taken into its first prefill
+    # call, its first token on the host, finished.
+    admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     # SLO scheduling (repro.serve.policy): admission class (None ->
@@ -212,7 +216,7 @@ def note_first_token(req: Request, logits, vocab: int,
     """
     nxt = int(jnp.argmax(logits[0, -1, :vocab]))
     req.generated.append(nxt)
-    req.first_token_at = time.time()
+    req.first_token_at = now()
     stats["ttft"].append(req.first_token_at - req.arrived)
 
 
@@ -307,7 +311,7 @@ class ServeEngine:
         self._cancelled: List[Request] = []
 
     def submit(self, req: Request) -> None:
-        req.arrived = time.time()
+        req.arrived = now()
         if req.klass is None:
             req.klass = self.default_klass
         self.policy.enqueue(self.queue, req)
@@ -332,7 +336,7 @@ class ServeEngine:
                 return False
         req.done = True
         req.finish_reason = FINISH_CANCELLED
-        req.finished_at = time.time()
+        req.finished_at = now()
         self._cancelled.append(req)
         self.stats["engine"]["cancelled"] += 1
         return True
@@ -353,13 +357,16 @@ class ServeEngine:
 
     def _prefill_one(self, req: Request):
         s = len(req.prompt)
-        tokens = jnp.asarray(req.prompt[None], jnp.int32)
-        batch = {"tokens": tokens}
-        enc = encoder_inputs(req, self.cfg)
-        if enc is not None:
-            batch["frontend_embeds"] = jnp.asarray(enc[None])
-        logits, cache = self.prefill_fn(self.params, batch)
-        note_first_token(req, logits, self.cfg.vocab_size, self.stats)
+        with span("serve.prefill", rids=req.rid, rows=1, bucket=s):
+            if req.admitted_at is None:
+                req.admitted_at = now()
+            tokens = jnp.asarray(req.prompt[None], jnp.int32)
+            batch = {"tokens": tokens}
+            enc = encoder_inputs(req, self.cfg)
+            if enc is not None:
+                batch["frontend_embeds"] = jnp.asarray(enc[None])
+            logits, cache = self.prefill_fn(self.params, batch)
+            note_first_token(req, logits, self.cfg.vocab_size, self.stats)
         return cache, s
 
     def _backfill_one(self, req: Request) -> None:
@@ -452,7 +459,7 @@ class ServeEngine:
                 if len(r.generated) >= r.max_new_tokens \
                         or pos >= self.max_seq - 1:
                     r.done = True
-                    r.finished_at = time.time()
+                    r.finished_at = now()
                     finished.append(r)
                 else:
                     still.append(r)

@@ -60,10 +60,14 @@ TTFT/TPOT here are *user-observed*: stamped at emission by the emit
 thread (submission -> first delivered token; mean gap thereafter), not
 at the engine's internal prefill, so queueing delay under load is part
 of the number — that is the point of the Poisson rows in
-``benchmarks/serve_bench.py``.
+``benchmarks/serve_bench.py``.  Every stamp is on
+:func:`repro.serve.api.now`; each request's handle carries its
+lifecycle (:class:`RequestHandle`), and the service's spans go to the
+profiler's host tracer (:mod:`repro.serve.spans`).
 """
 from __future__ import annotations
 
+import copy
 import queue
 import threading
 import time
@@ -72,9 +76,11 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.serve.api import (Completion, FINISH_ABORTED, FINISH_CANCELLED,
-                             FINISH_DEADLINE, FINISH_LENGTH, FINISH_MAX_SEQ)
+                             FINISH_DEADLINE, FINISH_LENGTH, FINISH_MAX_SEQ,
+                             now)
 from repro.serve.engine import Request
 from repro.serve.policy import KLASSES, RejectedError
+from repro.serve.spans import span
 
 _SHUTDOWN = object()
 
@@ -99,14 +105,28 @@ class RequestHandle:
     ``on_token`` callback (if given) runs on the emit thread, once per
     token, in generation order; a raising callback never disturbs the
     serve (the exception is kept on ``callback_error``).
+
+    Lifecycle stamps, seconds on :func:`repro.serve.api.now`, each set
+    where the work happens (``None`` until then): ``submitted_at``
+    (entry to ``submit``), ``queued_at`` (``submit`` hands the request
+    to the scheduler), ``admitted_at`` (the scheduler takes it into a
+    prefill call), ``first_token_at`` (its first token is on the host),
+    ``first_emitted_at`` (its first token is delivered), ``done_at``
+    (its completion is delivered); and ``lock_wait_s``, the time
+    ``submit`` waited for the frontend's mutex.
     """
 
     def __init__(self, rid: int, max_new_tokens: int,
                  on_token: Optional[Callable[[int], None]] = None):
         self.rid = rid
         self.max_new_tokens = max_new_tokens
-        self.submitted_at = time.time()
+        self.submitted_at = now()
+        self.queued_at: Optional[float] = None
+        self.admitted_at: Optional[float] = None
+        self.first_token_at: Optional[float] = None
         self.first_emitted_at: Optional[float] = None
+        self.done_at: Optional[float] = None
+        self.lock_wait_s = 0.0
         self.callback_error: Optional[BaseException] = None
         self._on_token = on_token
         self._tokens: List[int] = []
@@ -147,7 +167,7 @@ class RequestHandle:
     def _deliver(self, toks: Sequence[int]) -> None:
         for t in toks:
             if self.first_emitted_at is None:
-                self.first_emitted_at = time.time()
+                self.first_emitted_at = now()
             with self._lock:
                 self._tokens.append(t)
             if self._on_token is not None:
@@ -216,6 +236,10 @@ class ServeFrontend:
         self._handles: List[RequestHandle] = []
         self._completions: List[Completion] = []
         self._next_rid = 0
+        # Per request, in the order taken: seconds ``submit`` waited for
+        # the mutex, and from ``queued_at`` to ``admitted_at``.
+        self._lock_waits: List[float] = []
+        self._admit_waits: List[float] = []
         self._started = False
         self._scheduler_t: Optional[threading.Thread] = None
         self._emitter_t: Optional[threading.Thread] = None
@@ -266,38 +290,62 @@ class ServeFrontend:
         :class:`~repro.serve.policy.RejectedError` instead of queueing
         unboundedly.
         """
-        if self._stop.is_set():
-            raise RuntimeError("frontend is shut down")
-        if klass is not None and klass not in KLASSES:
-            raise ValueError(f"klass={klass!r} not in {KLASSES}")
-        if deadline is not None and deadline <= 0:
-            raise ValueError(f"deadline={deadline} must be > 0 seconds")
-        with self._mutex:
-            if self.max_queued is not None:
-                backlog = self._intake.qsize() + len(self._deferred)
-                if backlog >= self.max_queued:
-                    self.rejected += 1
-                    raise RejectedError(
-                        f"intake full ({backlog} >= max_queued="
-                        f"{self.max_queued})",
-                        retry_after=max(4 * self.idle_wait,
-                                        0.01 * backlog))
-            if rid is None:
-                rid = self._next_rid
-            self._next_rid = max(self._next_rid, rid) + 1
-        handle = RequestHandle(rid, max_new_tokens, on_token)
-        handle._cancel_cb = self._file_cancel
-        req = Request(rid=rid, prompt=np.asarray(prompt, np.int32),
-                      max_new_tokens=max_new_tokens,
-                      arrived=handle.submitted_at, klass=klass,
-                      deadline=None if deadline is None
-                      else handle.submitted_at + deadline)
-        with self._mutex:
-            self._handles.append(handle)
-        self.start()
-        self._intake.put((req, handle))
-        self._wake.set()
-        return handle
+        submitted_at = now()
+        with span("serve.submit") as sp:
+            if self._stop.is_set():
+                raise RuntimeError("frontend is shut down")
+            if klass is not None and klass not in KLASSES:
+                raise ValueError(f"klass={klass!r} not in {KLASSES}")
+            if deadline is not None and deadline <= 0:
+                raise ValueError(f"deadline={deadline} must be > 0 seconds")
+            waited = self._take_mutex(rid)
+            try:
+                if self.max_queued is not None:
+                    backlog = self._intake.qsize() + len(self._deferred)
+                    if backlog >= self.max_queued:
+                        self.rejected += 1
+                        raise RejectedError(
+                            f"intake full ({backlog} >= max_queued="
+                            f"{self.max_queued})",
+                            retry_after=max(4 * self.idle_wait,
+                                            0.01 * backlog))
+                if rid is None:
+                    rid = self._next_rid
+                self._next_rid = max(self._next_rid, rid) + 1
+            finally:
+                self._mutex.release()
+            sp.set_metadata(rid=rid)
+            handle = RequestHandle(rid, max_new_tokens, on_token)
+            handle.submitted_at = submitted_at
+            handle._cancel_cb = self._file_cancel
+            req = Request(rid=rid, prompt=np.asarray(prompt, np.int32),
+                          max_new_tokens=max_new_tokens,
+                          arrived=submitted_at, klass=klass,
+                          deadline=None if deadline is None
+                          else submitted_at + deadline)
+            waited += self._take_mutex(rid)
+            try:
+                handle.lock_wait_s = waited
+                self._lock_waits.append(waited)
+                self._handles.append(handle)
+            finally:
+                self._mutex.release()
+            self.start()
+            handle.queued_at = now()
+            self._intake.put((req, handle))
+            self._wake.set()
+            return handle
+
+    def _take_mutex(self, rid: Optional[int]) -> float:
+        """Take ``_mutex`` for ``submit`` (span ``serve.submit.lock``)
+        and return the seconds waited; the caller releases it.  With
+        ``rid`` None the span names the id the request is about to
+        take, read once the mutex is held."""
+        t0 = now()
+        with span("serve.submit.lock") as sp:
+            self._mutex.acquire()
+            sp.set_metadata(rid=self._next_rid if rid is None else rid)
+        return now() - t0
 
     def _file_cancel(self, rid: int) -> None:
         """File a cancellation (any thread); the scheduler reaps it at
@@ -309,11 +357,11 @@ class ServeFrontend:
     def drain(self, timeout: Optional[float] = None) -> List[Completion]:
         """Block until every submitted request has completed; returns
         all completions so far in submission order."""
-        deadline = None if timeout is None else time.time() + timeout
+        deadline = None if timeout is None else now() + timeout
         with self._mutex:
             pending = list(self._handles)
         for h in pending:
-            left = None if deadline is None else deadline - time.time()
+            left = None if deadline is None else deadline - now()
             if not h._done.wait(left if left is None else max(left, 0)):
                 raise TimeoutError(
                     f"drain timed out with request {h.rid} in flight")
@@ -355,86 +403,103 @@ class ServeFrontend:
         cancelled or deadline-expired before admission resolve here
         without ever touching the engine.
         """
-        eng = self.engine
-        with self._mutex:
-            cap = self._free_capacity()
-            pending = self._deferred
-            self._deferred = []
-            cancels = set(self._cancels)
-        while True:
-            try:
-                pending.append(self._intake.get_nowait())
-            except queue.Empty:
-                break
-        if not pending:
-            return False
-        policy = getattr(eng, "policy", None)
-        now = time.time()
-        admit: List[Tuple[Request, RequestHandle]] = []
-        defer: List[Tuple[Request, RequestHandle]] = []
-        resolved: List[Tuple[Request, RequestHandle, str]] = []
-        n_batch = 0
-        for req, handle in pending:
-            if req.klass is None:
-                # prefill_batch skips engine.submit(), so the engine
-                # default class is stamped here.
-                req.klass = getattr(eng, "default_klass", None)
-            if req.rid in cancels:
-                resolved.append((req, handle, FINISH_CANCELLED))
-            elif req.deadline is not None and now >= req.deadline:
-                resolved.append((req, handle, FINISH_DEADLINE))
-            elif policy is not None and policy.class_priority \
-                    and policy.is_interactive(req):
-                admit.append((req, handle))
-            elif n_batch < cap:
-                admit.append((req, handle))
-                n_batch += 1
-            else:
-                defer.append((req, handle))
-        with self._mutex:
-            for req, handle, reason in resolved:
-                req.done = True
-                req.finish_reason = reason
-                self._cancels.discard(req.rid)
-                self._backlog.put((handle, _Done(req, reason=reason)))
-            self._deferred = defer + self._deferred
-            if admit:
-                for req, handle in admit:
-                    self._tracked[req.rid] = [req, handle, 0]
-                if hasattr(eng, "prefill_batch"):
-                    # Same-bucket arrivals prefill as one batched call;
-                    # the rows park decode-ready in the engine's
-                    # backfill queue and the next window admits them in
-                    # policy order.
-                    key = (lambda item:
-                           eng._bucket_len(len(item[0].prompt))
-                           or len(item[0].prompt))
-                    ordered = sorted(admit, key=key)
-                    eng.prefill_batch([req for req, _ in ordered])
-                    self.coalesced_prefills += 1
+        with span("serve.intake") as sp:
+            eng = self.engine
+            with self._mutex:
+                cap = self._free_capacity()
+                pending = self._deferred
+                self._deferred = []
+                cancels = set(self._cancels)
+            while True:
+                try:
+                    pending.append(self._intake.get_nowait())
+                except queue.Empty:
+                    break
+            if not pending:
+                sp.set_metadata(admitted=0, deferred=0)
+                return False
+            policy = getattr(eng, "policy", None)
+            t = now()
+            admit: List[Tuple[Request, RequestHandle]] = []
+            defer: List[Tuple[Request, RequestHandle]] = []
+            resolved: List[Tuple[Request, RequestHandle, str]] = []
+            n_batch = 0
+            for req, handle in pending:
+                if req.klass is None:
+                    # prefill_batch skips engine.submit(), so the engine
+                    # default class is stamped here.
+                    req.klass = getattr(eng, "default_klass", None)
+                if req.rid in cancels:
+                    resolved.append((req, handle, FINISH_CANCELLED))
+                elif req.deadline is not None and t >= req.deadline:
+                    resolved.append((req, handle, FINISH_DEADLINE))
+                elif policy is not None and policy.class_priority \
+                        and policy.is_interactive(req):
+                    admit.append((req, handle))
+                elif n_batch < cap:
+                    admit.append((req, handle))
+                    n_batch += 1
                 else:
-                    for req, _ in admit:
-                        eng.submit(req)
-                # The engines' submit() stamps arrival at queue time;
-                # restore the true submission stamps.
-                for req, handle in admit:
-                    req.arrived = handle.submitted_at
-                self._emit_new()
-        return bool(admit) or bool(resolved)
+                    defer.append((req, handle))
+            sp.set_metadata(admitted=len(admit), deferred=len(defer))
+            with self._mutex:
+                for req, handle, reason in resolved:
+                    req.done = True
+                    req.finish_reason = reason
+                    self._cancels.discard(req.rid)
+                    self._backlog.put((handle, _Done(req, reason=reason)))
+                self._deferred = defer + self._deferred
+                if admit:
+                    for req, handle in admit:
+                        self._tracked[req.rid] = [req, handle, 0]
+                    if hasattr(eng, "prefill_batch"):
+                        # Same-bucket arrivals prefill as one batched call;
+                        # the rows park decode-ready in the engine's
+                        # backfill queue and the next window admits them in
+                        # policy order.
+                        key = (lambda item:
+                               eng._bucket_len(len(item[0].prompt))
+                               or len(item[0].prompt))
+                        ordered = sorted(admit, key=key)
+                        eng.prefill_batch([req for req, _ in ordered])
+                        self.coalesced_prefills += 1
+                    else:
+                        for req, _ in admit:
+                            eng.submit(req)
+                    # The engines' submit() stamps arrival at queue time;
+                    # restore the true submission stamps.
+                    for req, handle in admit:
+                        req.arrived = handle.submitted_at
+                    self._emit_new()
+            return bool(admit) or bool(resolved)
 
     def _emit_new(self) -> None:
         """Push every not-yet-emitted token to the backlog (called with
-        the mutex held, scheduler thread only)."""
-        for rid in list(self._tracked):
-            req, handle, n = self._tracked[rid]
-            fresh = req.generated[n:]
-            if fresh:
-                self._backlog.put((handle, list(fresh)))
-                self._tracked[rid][2] = n + len(fresh)
-            if req.done:
-                self._backlog.put((handle, _Done(req)))
-                self._cancels.discard(rid)
-                del self._tracked[rid]
+        the mutex held, scheduler thread only).  A request's first push
+        copies its engine stamps to its handle."""
+        with span("serve.emit") as sp:
+            pushed = 0
+            for rid in list(self._tracked):
+                req, handle, n = self._tracked[rid]
+                fresh = req.generated[n:]
+                if fresh:
+                    if handle.first_token_at is None:
+                        self._copy_engine_stamps(req, handle)
+                    self._backlog.put((handle, list(fresh)))
+                    self._tracked[rid][2] = n + len(fresh)
+                    pushed += len(fresh)
+                if req.done:
+                    self._backlog.put((handle, _Done(req)))
+                    self._cancels.discard(rid)
+                    del self._tracked[rid]
+            sp.set_metadata(tokens=pushed)
+
+    def _copy_engine_stamps(self, req: Request,
+                            handle: RequestHandle) -> None:
+        handle.admitted_at = req.admitted_at
+        handle.first_token_at = req.first_token_at
+        if req.admitted_at is not None:
+            self._admit_waits.append(req.admitted_at - handle.queued_at)
 
     def _reap(self) -> int:
         """Resolve filed cancellations and expired deadlines for admitted
@@ -442,12 +507,12 @@ class ServeFrontend:
         already-generated tokens flush, the handle resolves with the
         lifecycle reason.  Pre-admission entries resolve at intake flush
         instead.  Returns the number of requests reaped."""
-        now = time.time()
+        t = now()
         victims: List[Tuple[int, str]] = []
         for rid, (req, _handle, _n) in self._tracked.items():
             if rid in self._cancels:
                 victims.append((rid, FINISH_CANCELLED))
-            elif req.deadline is not None and now >= req.deadline:
+            elif req.deadline is not None and t >= req.deadline:
                 victims.append((rid, FINISH_DEADLINE))
         for rid, reason in victims:
             req, handle, n = self._tracked.pop(rid)
@@ -502,7 +567,7 @@ class ServeFrontend:
                 if ev.kind == "cancel":
                     self._cancels.add(rid)
                 else:
-                    self._tracked[rid][0].deadline = time.time()
+                    self._tracked[rid][0].deadline = now()
                 did = 1
         elif ev.kind == "raise_callback":
             if self._tracked:
@@ -521,36 +586,38 @@ class ServeFrontend:
         while True:
             if self._abort.is_set():
                 break
-            moved = self._intake_flush()
-            with self._mutex:
-                self._apply_faults()
-                reaped = self._reap()
-                self._check_devices()
-                t0 = time.perf_counter()
-                consumed = self.engine.step(finished)
-                dt = time.perf_counter() - t0
-                if self.watchdog is not None and consumed:
-                    dt += self._slow_next
-                    self._slow_next = 0.0
-                    if self.watchdog.observe(self._step_idx, dt):
-                        # A stalled window is how a lost shard shows up
-                        # from inside the host loop — re-probe at once.
-                        self._check_devices()
-                    self._step_idx += 1
-                self._emit_new()
-                finished.clear()
-                if consumed or moved or reaped:
-                    # The fault clock ticks on productive cycles only,
-                    # so a plan replays identically regardless of how
-                    # long the scheduler idles between work.
-                    self._cycle += 1
-            if self._stop.is_set() and not consumed and not moved \
-                    and not reaped and self._intake.empty() \
-                    and not self._deferred:
-                break
-            if not moved and not consumed and not reaped:
-                self._wake.wait(self.idle_wait)
-                self._wake.clear()
+            with span("serve.cycle", cycle=self._cycle):
+                moved = self._intake_flush()
+                with self._mutex:
+                    self._apply_faults()
+                    reaped = self._reap()
+                    self._check_devices()
+                    t0 = time.perf_counter()
+                    consumed = self.engine.step(finished)
+                    dt = time.perf_counter() - t0
+                    if self.watchdog is not None and consumed:
+                        dt += self._slow_next
+                        self._slow_next = 0.0
+                        if self.watchdog.observe(self._step_idx, dt):
+                            # A stalled window is how a lost shard shows up
+                            # from inside the host loop — re-probe at once.
+                            self._check_devices()
+                        self._step_idx += 1
+                    self._emit_new()
+                    finished.clear()
+                    if consumed or moved or reaped:
+                        # The fault clock ticks on productive cycles only,
+                        # so a plan replays identically regardless of how
+                        # long the scheduler idles between work.
+                        self._cycle += 1
+                if self._stop.is_set() and not consumed and not moved \
+                        and not reaped and self._intake.empty() \
+                        and not self._deferred:
+                    break
+                if not moved and not consumed and not reaped:
+                    with span("serve.idle"):
+                        self._wake.wait(self.idle_wait)
+                    self._wake.clear()
         with self._mutex:
             self._heal_seized()
         if self._abort.is_set():
@@ -636,20 +703,22 @@ class ServeFrontend:
             if item is _SHUTDOWN:
                 break
             handle, payload = item
-            if isinstance(payload, _Done):
-                completion = self._completion_for(payload, handle)
-                with self._mutex:
-                    self._completions.append(completion)
-                handle._finish(completion)
-            else:
-                handle._deliver(payload)
+            with span("serve.deliver", rid=handle.rid):
+                if isinstance(payload, _Done):
+                    completion = self._completion_for(payload, handle)
+                    with self._mutex:
+                        self._completions.append(completion)
+                    handle._finish(completion)
+                else:
+                    handle._deliver(payload)
 
     def _completion_for(self, done: _Done, handle: RequestHandle
                         ) -> Completion:
         req = done.req
         n = len(req.generated)
-        first = handle.first_emitted_at or handle.submitted_at
-        now = time.time()
+        first = (handle.submitted_at if handle.first_emitted_at is None
+                 else handle.first_emitted_at)
+        handle.done_at = now()
         reason = done.reason or getattr(req, "finish_reason", None)
         if reason is None:
             reason = (FINISH_LENGTH if n >= req.max_new_tokens
@@ -657,16 +726,25 @@ class ServeFrontend:
         return Completion(
             rid=req.rid, tokens=tuple(req.generated),
             ttft=max(0.0, first - handle.submitted_at),
-            tpot=max(0.0, (now - first) / (n - 1)) if n > 1 else 0.0,
+            tpot=(max(0.0, (handle.done_at - first) / (n - 1)) if n > 1
+                  else 0.0),
             finish_reason=reason)
 
     # -- observability ---------------------------------------------------
     @property
     def stats(self) -> Dict[str, Any]:
-        """Snapshot of the wrapped engine's stats (shared schema)."""
-        import copy
+        """Snapshot of the wrapped engine's stats (shared schema), with
+        the frontend's own per-request counters beside the engine's
+        extras under ``stats["engine"]["frontend"]``: ``submit_lock_s``
+        (each request's ``lock_wait_s``, in submission order) and
+        ``admit_wait_s`` (``admitted_at - queued_at``, in the order the
+        first tokens came)."""
         with self._mutex:
-            return copy.deepcopy(self.engine.stats)
+            out = copy.deepcopy(self.engine.stats)
+            out["engine"]["frontend"] = {
+                "submit_lock_s": list(self._lock_waits),
+                "admit_wait_s": list(self._admit_waits)}
+            return out
 
     def metrics(self) -> Dict[str, Any]:
         """Frontend-level service metrics (user-observed latency)."""

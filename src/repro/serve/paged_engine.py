@@ -1210,7 +1210,7 @@ class PagedServeEngine(SlotServeEngine):
             self.stats["engine"]["pages_mapped_peak"] = mapped
 
     # -- window over the page pools --------------------------------------
-    def _window_call(self, rung: int, toks, pos, budget):
+    def _map_window(self, rung: int) -> None:
         # Map the pages this window can write (bounded by the per-slot
         # budget and max_seq, within each admission's reservation by
         # construction — the free list cannot underflow) and rotate the
@@ -1234,6 +1234,8 @@ class PagedServeEngine(SlotServeEngine):
                 ext["window_pages_reclaimed"] += self.cache.advance_ring(
                     slot, last // self.page_size)
         self._note_pages_peak()
+
+    def _window_call(self, rung: int, toks, pos, budget):
         self.cache.pools, toks, pos, budget, out = self._window_fn(
             self.params, self.cache.pools, self.cache.tables(), toks, pos,
             budget, rung=rung)

@@ -59,7 +59,6 @@ outputs are batch-invariant by construction.
 from __future__ import annotations
 
 from collections import deque
-import time
 from typing import Any, Callable, Deque, List, Optional, Sequence, Tuple
 
 import jax
@@ -68,13 +67,14 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import (cache_specs, param_specs, to_named)
-from repro.serve.api import completion_of, Completion, FINISH_CANCELLED
+from repro.serve.api import completion_of, Completion, FINISH_CANCELLED, now
 from repro.serve.engine import (effective_tokens, encoder_inputs,
                                 init_serve_stats, note_first_token,
                                 record_step_packing, Request, SLAB_LADDER)
 from repro.serve.policy import KLASS_BATCH, SchedulingPolicy
 from repro.serve.serve_step import (make_bucketed_prefill_step,
                                     make_decode_step)
+from repro.serve.spans import span
 
 PyTree = Any
 
@@ -462,7 +462,7 @@ class SlotServeEngine:
     def submit(self, req: Request) -> None:
         """Enqueue a request in admission-class order (interactive ahead
         of the first batch entry; FIFO within each class)."""
-        req.arrived = time.time()
+        req.arrived = now()
         if req.klass is None:
             req.klass = self.default_klass
         self.policy.enqueue(self.queue, req)
@@ -489,36 +489,39 @@ class SlotServeEngine:
         toks = effective_tokens(req)
         resume = bool(req.generated)
         s = len(toks)
-        if self._bucket_enabled:
-            b = self._bucket_len(s)
-            if b is not None:
-                if b in self._seen_buckets:
-                    self.stats["engine"]["prefill_bucket_hits"] += 1
+        b = self._bucket_len(s) if self._bucket_enabled else None
+        with span("serve.prefill", rids=req.rid, rows=1, bucket=b or s):
+            if req.admitted_at is None:
+                req.admitted_at = now()
+            if self._bucket_enabled:
+                if b is not None:
+                    if b in self._seen_buckets:
+                        self.stats["engine"]["prefill_bucket_hits"] += 1
+                    else:
+                        self._seen_buckets.add(b)
+                        self.stats["engine"]["prefill_bucket_misses"] += 1
+                    padded = np.zeros(b, np.int32)
+                    padded[:s] = toks
+                    tokens = padded[None]
                 else:
-                    self._seen_buckets.add(b)
-                    self.stats["engine"]["prefill_bucket_misses"] += 1
-                padded = np.zeros(b, np.int32)
-                padded[:s] = toks
-                tokens = padded[None]
+                    # Prompt exceeds the engine capacity: exact-length
+                    # fallback, distinct from a first-seen bucket (misses
+                    # compile once and then hit; fallbacks compile per
+                    # unique length every time).
+                    self.stats["engine"]["prefill_bucket_fallbacks"] += 1
+                    tokens = np.asarray(toks[None], np.int32)
+                batch = {"tokens": jnp.asarray(tokens),
+                         "last_index": jnp.int32(s - 1)}
             else:
-                # Prompt exceeds the engine capacity: exact-length
-                # fallback, distinct from a first-seen bucket (misses
-                # compile once and then hit; fallbacks compile per
-                # unique length every time).
-                self.stats["engine"]["prefill_bucket_fallbacks"] += 1
-                tokens = np.asarray(toks[None], np.int32)
-            batch = {"tokens": jnp.asarray(tokens),
-                     "last_index": jnp.int32(s - 1)}
-        else:
-            batch = {"tokens": jnp.asarray(toks[None], jnp.int32)}
-            if self._prefill_needs_index:
-                batch["last_index"] = jnp.int32(s - 1)
-        enc = encoder_inputs(req, self.cfg)
-        if enc is not None:
-            batch["frontend_embeds"] = jnp.asarray(enc[None])
-        logits, cache = self.prefill_fn(self.params, batch)
-        if not resume:
-            note_first_token(req, logits, self.cfg.vocab_size, self.stats)
+                batch = {"tokens": jnp.asarray(toks[None], jnp.int32)}
+                if self._prefill_needs_index:
+                    batch["last_index"] = jnp.int32(s - 1)
+            enc = encoder_inputs(req, self.cfg)
+            if enc is not None:
+                batch["frontend_embeds"] = jnp.asarray(enc[None])
+            logits, cache = self.prefill_fn(self.params, batch)
+            if not resume:
+                note_first_token(req, logits, self.cfg.vocab_size, self.stats)
         return cache, s
 
     def _backfill_one(self, req: Request) -> None:
@@ -699,7 +702,7 @@ class SlotServeEngine:
                     return False
         req.done = True
         req.finish_reason = FINISH_CANCELLED
-        req.finished_at = time.time()
+        req.finished_at = now()
         self._cancelled.append(req)
         self.stats["engine"]["cancelled"] += 1
         return True
@@ -719,6 +722,10 @@ class SlotServeEngine:
         also retires freed physical pages from its prefix registry)."""
         self.cache.release(slot)
 
+    def _map_window(self, rung: int) -> None:
+        """Make storage ready for the window at ``rung`` (hook: the
+        paged engine maps the pages the window can write)."""
+
     def _window_call(self, rung: int, toks, pos, budget):
         """Invoke the jitted window at ``rung`` (storage-specific)."""
         self.cache.buffers, toks, pos, budget, out = self._window_fn(
@@ -726,10 +733,13 @@ class SlotServeEngine:
         return toks, pos, budget, out
 
     def _run_window(self, rung: int, finished: List[Request]) -> None:
-        toks = jnp.asarray(self._tok[:rung])
-        pos = jnp.asarray(self._pos[:rung])
-        budget = jnp.asarray(self._budget[:rung])
-        toks, pos, budget, out = self._window_call(rung, toks, pos, budget)
+        with span("serve.window.prep", rung=rung):
+            self._map_window(rung)
+            toks = jnp.asarray(self._tok[:rung])
+            pos = jnp.asarray(self._pos[:rung])
+            budget = jnp.asarray(self._budget[:rung])
+        with span("serve.window.dispatch", rung=rung):
+            toks, pos, budget, out = self._window_call(rung, toks, pos, budget)
         entries = jit_cache_entries(self._window_fn)
         raw = entries if entries is not None else self._window_traces
         self.stats["decode_compiles"] = max(0, raw - self._compile_base)
@@ -737,23 +747,27 @@ class SlotServeEngine:
         self.stats["engine"]["rungs"].append(rung)
         self.stats["decode_steps"] += self.window
         # The single host sync of the window:
-        out_np = np.asarray(out)                         # (T, rung)
-        self._tok[:rung] = np.asarray(toks)
-        self._pos[:rung] = np.asarray(pos)
-        self._budget[:rung] = np.asarray(budget)
-        for slot in range(rung):
-            req = self._req[slot]
-            if req is None:
-                continue
-            col = out_np[:, slot]
-            req.generated.extend(int(t) for t in col[col >= 0])
-            if self._budget[slot] <= 0:
-                req.done = True
-                req.finished_at = time.time()
-                finished.append(req)
-                self._req[slot] = None
-                self._release_slot(slot)
-                self.stats["engine"]["slot_releases"] += 1
+        with span("serve.window.sync", rung=rung):
+            out_np = np.asarray(out)                     # (T, rung)
+        with span("serve.window.post", rung=rung) as sp:
+            self._tok[:rung] = np.asarray(toks)
+            self._pos[:rung] = np.asarray(pos)
+            self._budget[:rung] = np.asarray(budget)
+            n_done = len(finished)
+            for slot in range(rung):
+                req = self._req[slot]
+                if req is None:
+                    continue
+                col = out_np[:, slot]
+                req.generated.extend(int(t) for t in col[col >= 0])
+                if self._budget[slot] <= 0:
+                    req.done = True
+                    req.finished_at = now()
+                    finished.append(req)
+                    self._req[slot] = None
+                    self._release_slot(slot)
+                    self.stats["engine"]["slot_releases"] += 1
+            sp.set_metadata(finished=len(finished) - n_done)
 
     def _plan_step(self) -> int:
         """Multi-tenant co-schedule of this window (stats + backfill
@@ -777,30 +791,37 @@ class SlotServeEngine:
         between two calls the engine state is at a window boundary, so
         the frontend can inject batched prefills and read fresh tokens.
         """
-        if self._cancelled:
-            finished.extend(self._cancelled)
-            self._cancelled.clear()
-        if not (self.queue or self._backfilled or self._n_active()) \
-                or max_steps <= 0:
-            return 0
-        self._admit()
-        n_pre = self._plan_step()
-        to_backfill: List[Request] = []
-        if self.coexec_backend and self.multi_tenant:
-            nb = min(n_pre, len(self.queue))
-            to_backfill = [self.queue.popleft() for _ in range(nb)]
-        rung = self._current_rung()
-        if rung:
-            self._run_window(rung, finished)
-            consumed = self.window
-        else:
-            consumed = 1
-        # Co-scheduled prefills run at the window boundary (the
-        # fused grid interleaves them with the decode window on the
-        # array; at the host level they fill the sync gap).
-        for r in to_backfill:
-            self._backfill_one(r)
-        return consumed
+        with span("serve.step") as sp:
+            if self._cancelled:
+                finished.extend(self._cancelled)
+                self._cancelled.clear()
+            if not (self.queue or self._backfilled or self._n_active()) \
+                    or max_steps <= 0:
+                sp.set_metadata(rung=0)
+                return 0
+            with span("serve.admit") as admit:
+                n0 = self.stats["engine"]["slot_admits"]
+                self._admit()
+                admit.set_metadata(
+                    admitted=self.stats["engine"]["slot_admits"] - n0)
+            n_pre = self._plan_step()
+            to_backfill: List[Request] = []
+            if self.coexec_backend and self.multi_tenant:
+                nb = min(n_pre, len(self.queue))
+                to_backfill = [self.queue.popleft() for _ in range(nb)]
+            rung = self._current_rung()
+            sp.set_metadata(rung=rung)
+            if rung:
+                self._run_window(rung, finished)
+                consumed = self.window
+            else:
+                consumed = 1
+            # Co-scheduled prefills run at the window boundary (the
+            # fused grid interleaves them with the decode window on the
+            # array; at the host level they fill the sync gap).
+            for r in to_backfill:
+                self._backfill_one(r)
+            return consumed
 
     def run(self, max_steps: int = 512) -> List[Completion]:
         """Serve everything in the queue (greedy decoding); returns one
@@ -855,30 +876,37 @@ class SlotServeEngine:
     def _prefill_group(self, group: List[Request], b: int) -> None:
         k = len(group)
         rung = next(r for r in self.rungs if r >= k)
-        sig = (rung, b)
-        if sig in self._seen_buckets:
-            self.stats["engine"]["prefill_bucket_hits"] += 1
-        else:
-            self._seen_buckets.add(sig)
-            self.stats["engine"]["prefill_bucket_misses"] += 1
-        toks = np.zeros((rung, b), np.int32)
-        last = np.zeros(rung, np.int32)
-        for i in range(rung):
-            src = group[i] if i < k else group[0]
-            toks[i, :len(src.prompt)] = src.prompt
-            last[i] = len(src.prompt) - 1
-        batch = {"tokens": jnp.asarray(toks),
-                 "last_index": jnp.asarray(last)}
-        if self.cfg.enc_dec:
-            encs = [encoder_inputs(group[i] if i < k else group[0],
-                                   self.cfg) for i in range(rung)]
-            batch["frontend_embeds"] = jnp.asarray(np.stack(encs))
-        logits, cache = self.prefill_fn(self.params, batch)
-        for i, req in enumerate(group):
-            note_first_token(req, logits[i:i + 1], self.cfg.vocab_size,
-                             self.stats)
-            row = jax.tree.map(lambda x, i=i: x[:, i:i + 1], cache)
-            self._backfilled.append((req, row, len(req.prompt)))
+        # Stat values may not hold commas: the ids are space-separated.
+        with span("serve.prefill", rids=" ".join(str(r.rid) for r in group),
+                  rows=rung, bucket=b):
+            t = now()
+            for req in group:
+                if req.admitted_at is None:
+                    req.admitted_at = t
+            sig = (rung, b)
+            if sig in self._seen_buckets:
+                self.stats["engine"]["prefill_bucket_hits"] += 1
+            else:
+                self._seen_buckets.add(sig)
+                self.stats["engine"]["prefill_bucket_misses"] += 1
+            toks = np.zeros((rung, b), np.int32)
+            last = np.zeros(rung, np.int32)
+            for i in range(rung):
+                src = group[i] if i < k else group[0]
+                toks[i, :len(src.prompt)] = src.prompt
+                last[i] = len(src.prompt) - 1
+            batch = {"tokens": jnp.asarray(toks),
+                     "last_index": jnp.asarray(last)}
+            if self.cfg.enc_dec:
+                encs = [encoder_inputs(group[i] if i < k else group[0],
+                                       self.cfg) for i in range(rung)]
+                batch["frontend_embeds"] = jnp.asarray(np.stack(encs))
+            logits, cache = self.prefill_fn(self.params, batch)
+            for i, req in enumerate(group):
+                note_first_token(req, logits[i:i + 1], self.cfg.vocab_size,
+                                 self.stats)
+                row = jax.tree.map(lambda x, i=i: x[:, i:i + 1], cache)
+                self._backfilled.append((req, row, len(req.prompt)))
         self.stats["engine"]["prefill_batches"] += 1
         self.stats["engine"]["prefill_batched_reqs"] += k
 
