@@ -21,6 +21,18 @@ needs: an always-on service wrapping one engine, with
   engine is never touched off this thread, so the engines stay
   single-threaded internally.
 
+* **Two locks**: the *engine lock* (``_mutex``) is held by the
+  scheduler through all engine work — each prefill, decode window,
+  emit, reap, fault and remesh — and by :meth:`warmup` and
+  :attr:`stats`, which read the engine.  The *bookkeeping lock*
+  (``_books``) guards only frontend state: rid allocation, the handles,
+  the per-request lock waits, filed cancels, completions, the rejection
+  count and the number of requests waiting for admission.  It is never
+  held across engine or device work, and no thread takes the engine
+  lock while holding it.  :meth:`submit`, cancellation,
+  :meth:`drain`, :meth:`metrics` and the emit thread take only the
+  bookkeeping lock, so none of them waits out a decode window.
+
 * **Detokenize/emit thread**: a second thread drains the backlog into
   per-request delivery — appending to the handle's token stream and
   invoking its callback in strict per-request order (tokens, then the
@@ -113,7 +125,8 @@ class RequestHandle:
     prefill call), ``first_token_at`` (its first token is on the host),
     ``first_emitted_at`` (its first token is delivered), ``done_at``
     (its completion is delivered); and ``lock_wait_s``, the time
-    ``submit`` waited for the frontend's mutex.
+    ``submit`` waited for the frontend's bookkeeping lock (never held
+    across engine work, so this is not the wait for a decode window).
     """
 
     def __init__(self, rid: int, max_new_tokens: int,
@@ -187,9 +200,14 @@ class ServeFrontend:
 
     Threads start lazily at the first :meth:`submit` (or explicitly via
     :meth:`start`); the instance is a context manager whose exit drains
-    and shuts down.  Only the scheduler thread ever touches the engine;
-    :attr:`stats` and :meth:`metrics` take the same mutex, so they can
-    be read at any time.
+    and shuts down.  Only the scheduler thread ever touches the engine.
+    It holds the engine lock ``_mutex`` through engine work; callers and
+    the emit thread take only the bookkeeping lock ``_books``, which
+    guards frontend state and is never held across engine work.  Lock
+    order: ``_books`` may be taken while ``_mutex`` is held, never the
+    reverse.  :meth:`metrics` can be read at any time without waiting on
+    the engine; :attr:`stats` reads the engine, so it waits for the
+    engine lock.
     """
 
     def __init__(self, engine, *, idle_wait: float = 0.002,
@@ -213,33 +231,40 @@ class ServeFrontend:
         self.max_queued = max_queued
         self.fault_plan = fault_plan
         self.fault_log: List[Tuple[int, str, int]] = []
-        self.rejected = 0
         self._cycle = 0
         self._seized_pages: List[int] = []
-        self._cancels: set = set()
         self._slow_next = 0.0          # straggler-fault dt inflation
         self._fault_cursor = -1        # last cycle whose faults fired
-        # Admitted-capacity overflow (batch-class only — interactive
-        # arrivals bypass the capacity cap so preemption can serve
-        # them); scheduler thread only, length read under the mutex.
-        self._deferred: List[Tuple[Request, RequestHandle]] = []
         self._healthy_n: Optional[int] = None
         self._step_idx = 0
         self._intake: "queue.Queue" = queue.Queue()
         self._backlog: "queue.Queue" = queue.Queue()
-        self._mutex = threading.Lock()      # engine + tracking state
+        self._mutex = threading.Lock()      # engine lock: engine work
+        self._books = threading.Lock()      # bookkeeping lock (below)
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._abort = threading.Event()
         # rid -> (req, handle, n_emitted); scheduler thread only.
         self._tracked: Dict[int, List[Any]] = {}
+        # Scheduler thread only, under the engine lock: seconds from
+        # ``queued_at`` to ``admitted_at``, in the order first tokens came.
+        self._admit_waits: List[float] = []
+        # Under ``_books``: -------------------------------------------
+        self.rejected = 0
+        self._cancels: set = set()
         self._handles: List[RequestHandle] = []
         self._completions: List[Completion] = []
         self._next_rid = 0
-        # Per request, in the order taken: seconds ``submit`` waited for
-        # the mutex, and from ``queued_at`` to ``admitted_at``.
+        # Seconds each ``submit`` waited for ``_books``, one per request.
         self._lock_waits: List[float] = []
-        self._admit_waits: List[float] = []
+        # Requests accepted and not yet admitted, resolved or aborted:
+        # in the intake queue, deferred, or in the scheduler's hands
+        # mid-flush.  ``max_queued`` bounds it.
+        self._waiting = 0
+        # Admitted-capacity overflow (batch-class only — interactive
+        # arrivals bypass the capacity cap so preemption can serve
+        # them); changed by the scheduler thread only.
+        self._deferred: List[Tuple[Request, RequestHandle]] = []
         self._started = False
         self._scheduler_t: Optional[threading.Thread] = None
         self._emitter_t: Optional[threading.Thread] = None
@@ -249,14 +274,19 @@ class ServeFrontend:
     def start(self) -> "ServeFrontend":
         if self._started:
             return self
-        self._started = True
-        self._scheduler_t = threading.Thread(target=self._scheduler,
-                                             name="serve-scheduler",
-                                             daemon=True)
-        self._emitter_t = threading.Thread(target=self._emitter,
-                                           name="serve-emit", daemon=True)
-        self._scheduler_t.start()
-        self._emitter_t.start()
+        with self._books:
+            # Concurrent first submits must start one scheduler, not two.
+            if self._started:
+                return self
+            self._started = True
+            self._scheduler_t = threading.Thread(target=self._scheduler,
+                                                 name="serve-scheduler",
+                                                 daemon=True)
+            self._emitter_t = threading.Thread(target=self._emitter,
+                                               name="serve-emit",
+                                               daemon=True)
+            self._scheduler_t.start()
+            self._emitter_t.start()
         return self
 
     def __enter__(self) -> "ServeFrontend":
@@ -298,59 +328,50 @@ class ServeFrontend:
                 raise ValueError(f"klass={klass!r} not in {KLASSES}")
             if deadline is not None and deadline <= 0:
                 raise ValueError(f"deadline={deadline} must be > 0 seconds")
-            waited = self._take_mutex(rid)
+            prompt = np.asarray(prompt, np.int32)
+            self.start()
+            t0 = now()
+            with span("serve.submit.lock") as lsp:
+                self._books.acquire()
+                # With ``rid`` None, the id the request is about to take.
+                lsp.set_metadata(rid=self._next_rid if rid is None else rid)
+            waited = now() - t0
             try:
-                if self.max_queued is not None:
-                    backlog = self._intake.qsize() + len(self._deferred)
-                    if backlog >= self.max_queued:
-                        self.rejected += 1
-                        raise RejectedError(
-                            f"intake full ({backlog} >= max_queued="
-                            f"{self.max_queued})",
-                            retry_after=max(4 * self.idle_wait,
-                                            0.01 * backlog))
+                if self.max_queued is not None \
+                        and self._waiting >= self.max_queued:
+                    self.rejected += 1
+                    raise RejectedError(
+                        f"intake full ({self._waiting} >= max_queued="
+                        f"{self.max_queued})",
+                        retry_after=max(4 * self.idle_wait,
+                                        0.01 * self._waiting))
                 if rid is None:
                     rid = self._next_rid
                 self._next_rid = max(self._next_rid, rid) + 1
-            finally:
-                self._mutex.release()
-            sp.set_metadata(rid=rid)
-            handle = RequestHandle(rid, max_new_tokens, on_token)
-            handle.submitted_at = submitted_at
-            handle._cancel_cb = self._file_cancel
-            req = Request(rid=rid, prompt=np.asarray(prompt, np.int32),
-                          max_new_tokens=max_new_tokens,
-                          arrived=submitted_at, klass=klass,
-                          deadline=None if deadline is None
-                          else submitted_at + deadline)
-            waited += self._take_mutex(rid)
-            try:
+                handle = RequestHandle(rid, max_new_tokens, on_token)
+                handle.submitted_at = submitted_at
+                handle._cancel_cb = self._file_cancel
                 handle.lock_wait_s = waited
+                req = Request(rid=rid, prompt=prompt,
+                              max_new_tokens=max_new_tokens,
+                              arrived=submitted_at, klass=klass,
+                              deadline=None if deadline is None
+                              else submitted_at + deadline)
                 self._lock_waits.append(waited)
                 self._handles.append(handle)
+                self._waiting += 1
+                handle.queued_at = now()
+                self._intake.put((req, handle))
             finally:
-                self._mutex.release()
-            self.start()
-            handle.queued_at = now()
-            self._intake.put((req, handle))
+                self._books.release()
+            sp.set_metadata(rid=rid)
             self._wake.set()
             return handle
-
-    def _take_mutex(self, rid: Optional[int]) -> float:
-        """Take ``_mutex`` for ``submit`` (span ``serve.submit.lock``)
-        and return the seconds waited; the caller releases it.  With
-        ``rid`` None the span names the id the request is about to
-        take, read once the mutex is held."""
-        t0 = now()
-        with span("serve.submit.lock") as sp:
-            self._mutex.acquire()
-            sp.set_metadata(rid=self._next_rid if rid is None else rid)
-        return now() - t0
 
     def _file_cancel(self, rid: int) -> None:
         """File a cancellation (any thread); the scheduler reaps it at
         its next cycle."""
-        with self._mutex:
+        with self._books:
             self._cancels.add(rid)
         self._wake.set()
 
@@ -358,14 +379,14 @@ class ServeFrontend:
         """Block until every submitted request has completed; returns
         all completions so far in submission order."""
         deadline = None if timeout is None else now() + timeout
-        with self._mutex:
+        with self._books:
             pending = list(self._handles)
         for h in pending:
             left = None if deadline is None else deadline - now()
             if not h._done.wait(left if left is None else max(left, 0)):
                 raise TimeoutError(
                     f"drain timed out with request {h.rid} in flight")
-        with self._mutex:
+        with self._books:
             return list(self._completions)
 
     def shutdown(self, drain: bool = True,
@@ -407,14 +428,15 @@ class ServeFrontend:
             eng = self.engine
             with self._mutex:
                 cap = self._free_capacity()
+            with self._books:
                 pending = self._deferred
                 self._deferred = []
                 cancels = set(self._cancels)
-            while True:
-                try:
-                    pending.append(self._intake.get_nowait())
-                except queue.Empty:
-                    break
+                while True:
+                    try:
+                        pending.append(self._intake.get_nowait())
+                    except queue.Empty:
+                        break
             if not pending:
                 sp.set_metadata(admitted=0, deferred=0)
                 return False
@@ -442,14 +464,16 @@ class ServeFrontend:
                 else:
                     defer.append((req, handle))
             sp.set_metadata(admitted=len(admit), deferred=len(defer))
-            with self._mutex:
+            with self._books:
                 for req, handle, reason in resolved:
                     req.done = True
                     req.finish_reason = reason
                     self._cancels.discard(req.rid)
                     self._backlog.put((handle, _Done(req, reason=reason)))
                 self._deferred = defer + self._deferred
-                if admit:
+                self._waiting -= len(admit) + len(resolved)
+            if admit:
+                with self._mutex:
                     for req, handle in admit:
                         self._tracked[req.rid] = [req, handle, 0]
                     if hasattr(eng, "prefill_batch"):
@@ -475,10 +499,11 @@ class ServeFrontend:
 
     def _emit_new(self) -> None:
         """Push every not-yet-emitted token to the backlog (called with
-        the mutex held, scheduler thread only).  A request's first push
-        copies its engine stamps to its handle."""
+        the engine lock held, scheduler thread only).  A request's first
+        push copies its engine stamps to its handle."""
         with span("serve.emit") as sp:
             pushed = 0
+            finished: List[int] = []
             for rid in list(self._tracked):
                 req, handle, n = self._tracked[rid]
                 fresh = req.generated[n:]
@@ -490,8 +515,11 @@ class ServeFrontend:
                     pushed += len(fresh)
                 if req.done:
                     self._backlog.put((handle, _Done(req)))
-                    self._cancels.discard(rid)
+                    finished.append(rid)
                     del self._tracked[rid]
+            if finished:
+                with self._books:
+                    self._cancels.difference_update(finished)
             sp.set_metadata(tokens=pushed)
 
     def _copy_engine_stamps(self, req: Request,
@@ -503,20 +531,21 @@ class ServeFrontend:
 
     def _reap(self) -> int:
         """Resolve filed cancellations and expired deadlines for admitted
-        requests (mutex held): the engine releases the slot/pages, any
-        already-generated tokens flush, the handle resolves with the
+        requests (engine lock held): the engine releases the slot/pages,
+        any already-generated tokens flush, the handle resolves with the
         lifecycle reason.  Pre-admission entries resolve at intake flush
         instead.  Returns the number of requests reaped."""
         t = now()
         victims: List[Tuple[int, str]] = []
-        for rid, (req, _handle, _n) in self._tracked.items():
-            if rid in self._cancels:
-                victims.append((rid, FINISH_CANCELLED))
-            elif req.deadline is not None and t >= req.deadline:
-                victims.append((rid, FINISH_DEADLINE))
+        with self._books:
+            for rid, (req, _handle, _n) in self._tracked.items():
+                if rid in self._cancels:
+                    victims.append((rid, FINISH_CANCELLED))
+                elif req.deadline is not None and t >= req.deadline:
+                    victims.append((rid, FINISH_DEADLINE))
+            self._cancels.difference_update(rid for rid, _ in victims)
         for rid, reason in victims:
             req, handle, n = self._tracked.pop(rid)
-            self._cancels.discard(rid)
             if hasattr(self.engine, "cancel"):
                 self.engine.cancel(rid)
             req.done = True
@@ -528,10 +557,10 @@ class ServeFrontend:
         return len(victims)
 
     def _apply_faults(self) -> None:
-        """Fire this cycle's scheduled fault events (mutex held).  The
-        cursor makes each cycle's events one-shot: the fault clock only
-        advances on productive cycles, and idle scheduler spins must not
-        replay the current cycle's storm."""
+        """Fire this cycle's scheduled fault events (engine lock held).
+        The cursor makes each cycle's events one-shot: the fault clock
+        only advances on productive cycles, and idle scheduler spins must
+        not replay the current cycle's storm."""
         if self.fault_plan is None or self._cycle == self._fault_cursor:
             return
         self._fault_cursor = self._cycle
@@ -565,7 +594,8 @@ class ServeFrontend:
             if self._tracked:
                 rid = min(self._tracked)
                 if ev.kind == "cancel":
-                    self._cancels.add(rid)
+                    with self._books:
+                        self._cancels.add(rid)
                 else:
                     self._tracked[rid][0].deadline = now()
                 did = 1
@@ -624,10 +654,10 @@ class ServeFrontend:
             self._abort_inflight()
 
     def _heal_seized(self) -> None:
-        """Return any still-seized pages at scheduler exit (mutex held):
-        the injector ghosts pool capacity, it never leaks it — a plan
-        whose ``heal_pages`` cycle was never reached must not leave the
-        pool short after shutdown."""
+        """Return any still-seized pages at scheduler exit (engine lock
+        held): the injector ghosts pool capacity, it never leaks it — a
+        plan whose ``heal_pages`` cycle was never reached must not leave
+        the pool short after shutdown."""
         if not self._seized_pages:
             return
         cache = getattr(self.engine, "cache", None)
@@ -639,8 +669,8 @@ class ServeFrontend:
 
     # -- fault recovery --------------------------------------------------
     def _check_devices(self) -> None:
-        """Probe device health (mutex held, scheduler thread only); a
-        shrunk probe triggers elastic recovery."""
+        """Probe device health (engine lock held, scheduler thread
+        only); a shrunk probe triggers elastic recovery."""
         if self.device_probe is None:
             return
         healthy = list(self.device_probe())
@@ -650,7 +680,7 @@ class ServeFrontend:
 
     def _recover(self, healthy) -> None:
         """Rebuild the engine's mesh on the surviving devices and release
-        the victims for re-prefill (mutex held).
+        the victims for re-prefill (engine lock held).
 
         The model axis is kept when it still fits and halved otherwise
         (param sharding must stay divisible); the data axis absorbs the
@@ -684,6 +714,7 @@ class ServeFrontend:
         with self._mutex:
             leftovers = list(self._tracked.values())
             self._tracked.clear()
+        with self._books:
             leftovers.extend([req, handle, 0]
                              for req, handle in self._deferred)
             self._deferred = []
@@ -693,6 +724,7 @@ class ServeFrontend:
                 except queue.Empty:
                     break
                 leftovers.append([req, handle, 0])
+            self._waiting = 0
         for req, handle, _n in leftovers:
             self._backlog.put((handle, _Done(req, aborted=True)))
 
@@ -706,7 +738,7 @@ class ServeFrontend:
             with span("serve.deliver", rid=handle.rid):
                 if isinstance(payload, _Done):
                     completion = self._completion_for(payload, handle)
-                    with self._mutex:
+                    with self._books:
                         self._completions.append(completion)
                     handle._finish(completion)
                 else:
@@ -738,17 +770,21 @@ class ServeFrontend:
         extras under ``stats["engine"]["frontend"]``: ``submit_lock_s``
         (each request's ``lock_wait_s``, in submission order) and
         ``admit_wait_s`` (``admitted_at - queued_at``, in the order the
-        first tokens came)."""
+        first tokens came).  Reading the engine, it waits for the engine
+        lock."""
         with self._mutex:
             out = copy.deepcopy(self.engine.stats)
+            with self._books:
+                lock_waits = list(self._lock_waits)
             out["engine"]["frontend"] = {
-                "submit_lock_s": list(self._lock_waits),
+                "submit_lock_s": lock_waits,
                 "admit_wait_s": list(self._admit_waits)}
             return out
 
     def metrics(self) -> Dict[str, Any]:
-        """Frontend-level service metrics (user-observed latency)."""
-        with self._mutex:
+        """Frontend-level service metrics (user-observed latency); takes
+        only the bookkeeping lock, so it never waits out engine work."""
+        with self._books:
             comps = list(self._completions)
             return {
                 "submitted": len(self._handles),

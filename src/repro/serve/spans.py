@@ -14,8 +14,8 @@ of one request together across threads.
 Spans, by thread (stats in brackets):
 
 - caller: ``serve.submit``, the whole of ``ServeFrontend.submit``
-  (``rid``); ``serve.submit.lock``, each wait there to take the
-  frontend's mutex (``rid``).
+  (``rid``); ``serve.submit.lock``, its wait there to take the
+  frontend's bookkeeping lock (``rid``).
 - scheduler: ``serve.cycle``, one pass of the scheduler loop
   (``cycle``); ``serve.intake``, admission of arrivals (``admitted``,
   ``deferred``); ``serve.prefill``, one prefill call up to its first

@@ -8,9 +8,13 @@ zero decode compiles.  The supporting contracts: per-request streaming
 order (tokens in generation order, then the Completion), drain blocking
 on inflight work, abortive shutdown resolving every handle, and the
 batched multi-prompt prefill being bitwise the single-prompt prefill
-per row (the invariant the identity claim stands on).
+per row (the invariant the identity claim stands on).  The frontend's
+locking: a caller's ``submit`` and ``cancel`` and the emit thread's
+delivery never wait for the scheduler's decode window, and concurrent
+submitters get unique rids and the offline tokens.
 """
 import threading
+import time
 
 import jax
 import numpy as np
@@ -18,7 +22,9 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.models import init_params
-from repro.serve import make_engine, Request, ServeFrontend, validate_stats
+from repro.serve import (make_engine, RejectedError, Request, ServeFrontend,
+                         validate_stats)
+from repro.serve.api import now
 
 MAX_SLOTS = 4
 MAX_SEQ = 64
@@ -51,6 +57,89 @@ def _offline(setup, prompts, budgets, kind="slot", **kw):
     return {c.rid: c.tokens for c in eng.run(max_steps=4096)}
 
 
+class _GatedEngine:
+    """A stub engine whose ``step()`` blocks on ``gate`` while a request
+    with a rid in ``hold`` is active, so the frontend's scheduler sits
+    inside a decode window holding the engine lock.  Each step gives
+    every active request one token (its rid)."""
+
+    max_batch = MAX_SLOTS
+
+    def __init__(self, hold):
+        self.hold = set(hold)
+        self.gate = threading.Event()
+        self.in_step = threading.Event()
+        self.queue, self._backfilled, self.active = [], [], []
+
+    def _n_active(self):
+        return len(self.active)
+
+    def submit(self, req):
+        req.admitted_at = now()
+        self.queue.append(req)
+
+    def cancel(self, rid):
+        self.active = [r for r in self.active if r.rid != rid]
+
+    def step(self, finished):
+        self.active += self.queue
+        self.queue = []
+        if not self.active:
+            return 0
+        if self.hold & {r.rid for r in self.active}:
+            self.in_step.set()
+            self.gate.wait(30)
+        for r in self.active:
+            if r.first_token_at is None:
+                r.first_token_at = now()
+            r.generated.append(r.rid)
+            r.done = len(r.generated) >= r.max_new_tokens
+        self.active = [r for r in self.active if not r.done]
+        return 1
+
+
+class TestLocking:
+    def test_submit_cancel_and_delivery_never_wait_on_a_step(self):
+        """While the scheduler is inside a step: a completion already in
+        the backlog is delivered, ``submit`` returns at once with a
+        near-zero lock wait, ``max_queued`` still counts what waits, and
+        ``cancel`` returns.  Released, every request resolves."""
+        eng = _GatedEngine(hold={1})
+        fe = ServeFrontend(eng, max_queued=2)
+        a_token = threading.Event()
+
+        def a_cb(_tok):
+            # Holds the emit thread until the step blocks, so that A's
+            # completion waits in the backlog behind this token.
+            a_token.set()
+            eng.in_step.wait(30)
+
+        try:
+            a = fe.submit([1, 2, 3], 1, on_token=a_cb)          # rid 0
+            assert a_token.wait(30)
+            fe.submit([4, 5], 2)                 # rid 1: its step blocks
+            assert eng.in_step.wait(30)
+            assert a.result(timeout=5).finish_reason == "length"
+            waiting = []
+            for prompt in ([6], [7]):                          # rids 2, 3
+                t0 = time.perf_counter()
+                waiting.append(fe.submit(prompt, 1))
+                assert time.perf_counter() - t0 < 0.05
+            assert all(h.lock_wait_s < 0.01 for h in waiting)
+            with pytest.raises(RejectedError):
+                fe.submit([8], 1)                # two wait for admission
+            assert waiting[1].cancel()
+            assert not eng.gate.is_set()         # all inside the one step
+        finally:
+            eng.gate.set()
+        done = fe.drain(timeout=30)
+        fe.shutdown()
+        assert {c.rid: c.finish_reason for c in done} == {
+            0: "length", 1: "length", 2: "length", 3: "cancelled"}
+        assert {c.rid: c.tokens for c in done}[1] == (1, 1)
+        assert fe.metrics()["rejected"] == 1
+
+
 class TestLifecycle:
     def test_out_of_order_arrivals_match_offline(self, setup):
         """Mixed bucket lengths submitted online — intake coalescing
@@ -74,6 +163,64 @@ class TestLifecycle:
             assert h.done and h.result(timeout=1) == c
             assert c.finish_reason == "length"
         validate_stats(fe.stats)
+
+    @pytest.mark.parametrize("lifecycle", ["complete", "cancel_and_deadline"])
+    def test_concurrent_submitters_match_offline(self, setup, lifecycle):
+        """Eight threads submit at once (in one case cancelling some
+        requests and giving others a 0.1 ms deadline): every rid is
+        unique, every handle resolves, and every stream is the offline
+        serve's, or a prefix of it where a cancel or deadline cut it."""
+        cfg, _ = setup
+        n_threads, per_thread = 8, 2
+        n = n_threads * per_thread
+        rng = np.random.default_rng(17)
+        lens = [int(x) for x in rng.integers(3, 24, size=n)]
+        budgets = [int(b) for b in rng.integers(2, 7, size=n)]
+        prompts = _workload(cfg, lens, seed=17)
+        want = _offline(setup, prompts, budgets, kind="paged", page_size=8)
+        cut = lifecycle == "cancel_and_deadline"
+
+        fe = ServeFrontend(_make(setup, kind="paged", page_size=8))
+        barrier = threading.Barrier(n_threads)
+        handles, errors = {}, []
+
+        def client(t):
+            try:
+                barrier.wait(timeout=30)
+                for j in range(t * per_thread, (t + 1) * per_thread):
+                    h = fe.submit(prompts[j], budgets[j],
+                                  deadline=1e-4 if cut and j % 4 == 1
+                                  else None)
+                    if cut and j % 4 == 3:
+                        h.cancel()
+                    handles[j] = h
+            except BaseException as e:  # noqa: B036 - reported below
+                errors.append(e)
+
+        threads = [threading.Thread(target=client, args=(t,))
+                   for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+        done = fe.drain(timeout=120)
+        fe.shutdown()
+        assert not errors
+        assert len(handles) == len(done) == n
+        rids = sorted(h.rid for h in handles.values())
+        assert rids == sorted(c.rid for c in done) == list(range(n))
+        reasons = set()
+        for j, h in handles.items():
+            c = h.result(timeout=1)
+            reasons.add(c.finish_reason)
+            assert tuple(h.tokens) == c.tokens
+            if c.finish_reason == "length":
+                assert c.tokens == want[j]
+            else:
+                assert cut and c.finish_reason == (
+                    "deadline" if j % 4 == 1 else "cancelled")
+                assert c.tokens == want[j][:len(c.tokens)]
+        assert (reasons != {"length"}) == cut
 
     def test_paged_engine_served_identically(self, setup):
         cfg, _ = setup

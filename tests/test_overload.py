@@ -378,9 +378,13 @@ class TestChaos:
         wd = StragglerWatchdog(threshold=3.0)
         fe = ServeFrontend(eng, fault_plan=plan, watchdog=wd)
         errs = []
-        hs = [fe.submit(p, b,
-                        on_token=(lambda t: None) if i else None)
-              for i, (p, b) in enumerate(zip(prompts, budgets))]
+        # The plan's cycles assume all five arrive in one intake flush:
+        # holding the engine lock keeps the scheduler from flushing
+        # until every submit (which never takes that lock) has returned.
+        with fe._mutex:
+            hs = [fe.submit(p, b,
+                            on_token=(lambda t: None) if i else None)
+                  for i, (p, b) in enumerate(zip(prompts, budgets))]
         done = {c.rid: c for c in fe.drain(timeout=300)}
         fe.shutdown()
         fired = {k for _, k, n in fe.fault_log if n > 0}

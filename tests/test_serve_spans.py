@@ -111,7 +111,7 @@ def test_rid_stats_link_each_request_across_threads(served):
         locks = [e for e in events
                  if e[0] == "serve.submit.lock" and e[4].get("rid") == h.rid]
         assert len(submit) == 1 and len(prefill) == 1, h.rid
-        assert len(locks) == 2 and len(deliver) >= 2, h.rid
+        assert len(locks) == 1 and len(deliver) >= 2, h.rid
         assert all(submit[0][1] <= e[1] and e[2] <= submit[0][2]
                    for e in locks)
         # Three threads: the caller, the scheduler and the emitter.
