@@ -1,8 +1,11 @@
 """One run of one cell: set-up, the timed window, the metrics, the check.
 
 The cell, its configuration, its traffic and its per-layer metrics are
-found by name from ``BENCHMARK.json``; nothing here knows a particular
-cell.  The program under test is driven through its public serving path:
+found by name from ``BENCHMARK.json``, and the configuration's block
+(the registry keys it must match, its weights' roles and shapes, its
+reference and its operation counts) by the name its file gives under
+``block``; nothing here knows a particular cell or architecture.  The
+program under test is driven through its public serving path:
 ``make_engine(kind="paged")`` -> ``ServeFrontend.warmup`` ->
 ``ServeFrontend.submit``.  The benchmark stamps when each request was
 due and when each of its tokens was delivered (``on_token``), and reads
@@ -41,19 +44,6 @@ SENDERS = 16
 TRACE_FROM, TRACE_SHARE = 0.25, 0.5
 TRACE_MAX_S = 8.0
 
-# Config keys the registry's ModelConfig must match, so the program runs
-# the configuration the file states (file key -> ModelConfig attribute).
-REGISTRY_KEYS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "head_dim": "resolved_head_dim", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "norm_eps": "norm_eps", "gated_mlp": "gated_mlp", "hidden_act": "act",
-    "use_bias": "use_bias", "tie_word_embeddings": "tie_embeddings",
-    "torch_dtype": "param_dtype",
-}
-
-
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
@@ -66,6 +56,7 @@ class Cell:
     name: str
     chips: int
     spec: dict                 # configs/<config>.json
+    block: Any                 # blocks/<spec["block"]>.py
     traffic: dict              # traffic/<traffic>.json
     end_to_end: List[dict]
     per_layer: List[dict]
@@ -87,30 +78,50 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     bench_dir = root / manifest["paths"][0]
     traffic = json.loads(
         (bench_dir / "traffic" / f"{w['traffic']}.json").read_text())
-    return Cell(name, w["chips"], spec, traffic,
+    return Cell(name, w["chips"], spec, block_of(spec, root), traffic,
                 [m for m in manifest["end_to_end"] if _applies(m, name)],
                 [m for m in manifest["per_layer"] if _applies(m, name)],
                 root)
 
 
-def metric_reader(name: str, root: Path = ROOT) -> Callable:
-    """``read(ctx)`` of ``metrics/<name>.py`` under the benchmark."""
+def _load(kind: str, name: str, root: Path):
+    """The module ``<kind>/<name>.py`` under the benchmark, by path."""
     manifest = json.loads((root / "BENCHMARK.json").read_text())
-    path = root / manifest["paths"][0] / "metrics" / f"{name}.py"
+    path = root / manifest["paths"][0] / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {kind} module {name!r} at {path}")
     mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-def program_config(spec: dict):
+def metric_reader(name: str, root: Path = ROOT) -> Callable:
+    """``read(ctx)`` of ``metrics/<name>.py`` under the benchmark."""
+    return _load("metrics", name, root).read
+
+
+def block_of(spec: dict, root: Path = ROOT):
+    """The module ``blocks/<spec["block"]>.py`` under the benchmark: what
+    the configuration's layers are (see ``blocks/dense.py``).  A file
+    that names no block, or one that is not there, is an error."""
+    if "block" not in spec:
+        raise KeyError(f"{spec.get('name')}: the configuration file names "
+                       "no block")
+    return _load("blocks", spec["block"], root)
+
+
+def program_config(spec: dict, root: Path = ROOT):
     """The registry's configuration named by ``spec["arch"]``, checked
-    against every number the file states."""
+    against every number the file states that its block's
+    ``REGISTRY_KEYS`` name."""
     from repro.configs import get_config
 
     cfg = get_config(spec["arch"])
-    for key, attr in REGISTRY_KEYS.items():
+    for key, attr in block_of(spec, root).REGISTRY_KEYS.items():
+        if key not in spec:
+            raise KeyError(f"{spec['name']}: the file states no {key}")
         if getattr(cfg, attr) != spec[key]:
             raise ValueError(f"{spec['name']}: the program runs {attr}="
                              f"{getattr(cfg, attr)!r}, the file states "
@@ -164,7 +175,7 @@ def setup(cell: Cell, seed: int, cfg, devices: list) -> Served:
                                  shapes)
     t0 = time.perf_counter()
     params = jax.block_until_ready(
-        weights.program_params(shapes, seed, shardings))
+        weights.program_params(shapes, seed, cell.block, shardings))
     log(f"[setup] weights {sum(x.nbytes for x in jax.tree.leaves(params))}"
         f" bytes from seed {seed} in {time.perf_counter() - t0:.2f} s")
     eng_opts = cell.traffic["engine"]
@@ -455,6 +466,8 @@ class LayerContext:
     # it was due to its first token, and to when ``submit`` returned.
     ttft_ms: List[float] = dataclasses.field(default_factory=list)
     submit_wait_ms: List[float] = dataclasses.field(default_factory=list)
+    # The configuration's block: the operations and bytes a reader counts.
+    block: Any = None
 
     def per_chip_ns(self, pattern: str, line: str = "modules") -> float:
         """Mean over chips of the time of events matching ``pattern``."""
@@ -481,7 +494,7 @@ def layer_context(cell: Cell, served: Served, win: Window, tr: dict,
     return LayerContext(cell.spec, len(served.devices), served.tp,
                         peaks_of(device_kind), devs, b - a, tr["stats0"],
                         tr["stats1"], decode_ctx, prompt_lens, peak_bytes,
-                        end_to_end(win)["ttft_ms"], waits)
+                        end_to_end(win)["ttft_ms"], waits, cell.block)
 
 
 def reduce_trace(trace_dir: str, tr: dict) -> List[xplane.DeviceTrace]:
@@ -558,12 +571,13 @@ def reference_logits(cell: Cell, seed: int, sample: Sample, device,
                      quant: Optional[str] = None):
     """The reference's logits at every served position of ``sample``
     (``quant`` selects the control), and the tokens served there."""
-    from benchmarks.chip.reference import Reference, teacher_forced
+    from benchmarks.chip.reference import teacher_forced
 
     chk, eng = cell.traffic["check"], cell.traffic["engine"]
     n_out = chk["requests"] * cell.traffic["output_len"]["max"]
-    ref = Reference(cell.spec, seed, chk["requests"], eng["max_seq"],
-                    chk["block"], quant=quant, device=device)
+    ref = cell.block.Reference(cell.spec, seed, chk["requests"],
+                               eng["max_seq"], chk["block"], quant=quant,
+                               device=device)
     seqs, pos, toks = teacher_forced(sample)
     return ref.logits(seqs, pos, n_out), toks
 

@@ -1,8 +1,10 @@
 """The median, over the requests submitted in the traced slice, of the
-time ``ServeFrontend.submit`` waited for the frontend's mutex, measured
-inside ``submit`` (frontend counter ``submit_lock_s``, one entry per
-request, in ``stats["engine"]["frontend"]``).  The scheduler holds that
-mutex through every engine step.  Unlike ``submit_wait_p50_ms`` it
+time ``ServeFrontend.submit`` waited for the frontend's bookkeeping lock
+(``_books``), measured inside ``submit`` (frontend counter
+``submit_lock_s``, one entry per request, in
+``stats["engine"]["frontend"]``).  No engine work holds that lock: the
+scheduler takes it only to read cancels and hand over results, never
+across a prefill or a decode window.  Unlike ``submit_wait_p50_ms`` it
 leaves out the benchmark's sender pool and the interpreter lock."""
 import statistics
 

@@ -1,7 +1,8 @@
 """The median, over requests due in the window, of the time from when a
 request was due until ``ServeFrontend.submit`` returned its handle, host
-clock: the wait for the frontend's lock, which the scheduler holds
-through each engine step.  It is part of every time to first token."""
+clock: the benchmark's sender pool, the interpreter lock and the wait
+inside ``submit`` for the frontend's bookkeeping lock, which no engine
+step holds.  It is part of every time to first token."""
 import statistics
 
 

@@ -1,28 +1,29 @@
-"""The plain float32 reference of the block this repository serves.
+"""What every block's plain float32 reference shares.
 
 Straightforward ``jax.numpy`` under ``jax.default_matmul_precision
 ("highest")``: no kernel, no cache, no batching of requests beyond
-padding them to one length.  It reads only the configuration's file and
-the seed; its weights come from :mod:`weights` one layer at a time, so
-it fits next to nothing else on one chip.
+padding them to one length.  A reference reads only the configuration's
+file and the seed; its weights come from :mod:`weights` one layer at a
+time, so it fits next to nothing else on one chip.
 
-The block, as the repository implements it (each departure from the
-published model is listed in the configuration's file):
+A configuration's block (``blocks/<name>.py``) subclasses
+:class:`Reference` with its layer's equations and weight layout.  What
+lies outside the layers is here, as the repository implements it (each
+departure from a published model is listed in the configuration's
+file):
 
     x = embed[token] * sqrt(hidden)
-    per layer:  h = rmsnorm(x);  x += o(attention(rope(q(h)), rope(k(h)), v(h)))
-                h = rmsnorm(x);  x += down(act(gate(h)) * up(h))   (gated)
-                                 x += down(act(up(h)))              (plain)
+    per layer:  the block's layer
     logits = rmsnorm(x) @ head^T
 
-RoPE rotates the two halves of each head; key/value head ``j`` serves
-query heads ``j * n_rep ... (j + 1) * n_rep - 1``; ``gelu`` is the tanh
-form.
+The helpers below (RMSNorm, the activations, RoPE, the projections, the
+control's rounding) are for any block to use.
 
-``quant="int8"`` or ``"fp8"`` is the control, the block computed as an
-int8 or float8 (e4m3) server would: every projection and the head take
-weights rounded with one scale per output channel and inputs rounded
-with one scale per token, the rest as above.
+``quant="int8"`` or ``"fp8"`` is the control, the model computed as an
+int8 or float8 (e4m3) server would: every projection (each ``*.w``
+role, contracted over its next-to-last axis) and the head take weights
+rounded with one scale per output channel and inputs rounded with one
+scale per token, the rest as in float32.
 """
 from __future__ import annotations
 
@@ -39,11 +40,11 @@ from benchmarks.chip import weights
 NEG = -1e30
 
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _act(name, x):
+def act(name, x):
     if name == "silu":
         return x * jax.nn.sigmoid(x)
     if name == "gelu":
@@ -52,7 +53,7 @@ def _act(name, x):
     raise ValueError(f"unknown activation {name!r}")
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: (B, S, H, hd), positions 0..S-1."""
     hd = x.shape[-1]
     freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
@@ -74,7 +75,9 @@ def quantize(w: jax.Array, axis: int, kind: str) -> jax.Array:
     return (w / scale).astype(jnp.float8_e4m3fn).astype(w.dtype) * scale
 
 
-def _linear(w, name, x, quant=None):
+def linear(w, name, x, quant=None):
+    """``x @ w[name.w]``, plus ``w[name.b]`` where the block has it;
+    ``quant`` rounds ``x`` per token first (the control)."""
     if quant is not None:
         x = quantize(x, -1, quant)
     y = x @ w[name + ".w"]
@@ -83,40 +86,29 @@ def _linear(w, name, x, quant=None):
     return y
 
 
-def _layer(spec, w, x, quant=None):
-    """One block over x: (B, S, d) float32; ``quant`` rounds the inputs
-    of the projections (the control)."""
-    b, s, _ = x.shape
-    h_n, kv_n = spec["num_attention_heads"], spec["num_key_value_heads"]
-    hd, eps = spec["head_dim"], spec["norm_eps"]
-    h = _rmsnorm(x, w["norm1"], eps)
-    q = _linear(w, "q", h, quant).reshape(b, s, h_n, hd)
-    k = _linear(w, "k", h, quant).reshape(b, s, kv_n, hd)
-    v = _linear(w, "v", h, quant).reshape(b, s, kv_n, hd)
-    q, k = _rope(q, spec["rope_theta"]), _rope(k, spec["rope_theta"])
-    q = q.reshape(b, s, kv_n, h_n // kv_n, hd)
-    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k) / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal, scores, NEG)
-    probs = jax.nn.softmax(scores, axis=-1)
-    att = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v).reshape(b, s, h_n * hd)
-    x = x + _linear(w, "o", att, quant)
-    h = _rmsnorm(x, w["norm2"], eps)
-    if spec["gated_mlp"]:
-        mid = _act(spec["hidden_act"], _linear(w, "gate", h, quant)) \
-            * _linear(w, "up", h, quant)
-    else:
-        mid = _act(spec["hidden_act"], _linear(w, "up", h, quant))
-    return x + _linear(w, "down", mid, quant)
-
-
 class Reference:
     """Logits of the reference at chosen positions of padded sequences.
 
     ``rows`` sequences of ``seq_len`` tokens run ``block`` at a time
     through each layer; the shapes are fixed per cell so the programs
-    compile once and come from the persistent cache afterwards.
+    compile once (once per kind of layer) and come from the persistent
+    cache afterwards.
+
+    A block's subclass sets ``layer(spec, w, x, quant)``, one layer over
+    ``x: (B, S, d)`` float32 with its weights ``w`` by role, and
+    ``layer_shapes(spec, layer)`` and ``expert_roles`` as its module
+    states them.
     """
+
+    expert_roles: frozenset = frozenset()
+
+    @staticmethod
+    def layer(spec: dict, w: dict, x: jax.Array, quant=None) -> jax.Array:
+        raise NotImplementedError
+
+    @staticmethod
+    def layer_shapes(spec: dict, layer: int) -> dict:
+        raise NotImplementedError
 
     def __init__(self, spec: dict, seed: int, rows: int, seq_len: int,
                  block: int, quant: Optional[str] = None, device=None):
@@ -127,18 +119,20 @@ class Reference:
         self.quant = quant
         self.device = device or jax.devices()[0]
         self.key = jax.device_put(weights.base_key(seed), self.device)
-        self._layer_w = jax.jit(self._weights_of_layer)
+        self._layer_w = jax.jit(self._weights_of_layer, static_argnums=2)
         if quant not in (None, "int8", "fp8"):
             raise ValueError(f"unknown control precision {quant!r}")
-        self._layer = jax.jit(functools.partial(_layer, spec, quant=quant))
+        self._layer = jax.jit(functools.partial(self.layer, spec,
+                                                quant=quant))
         self._embed = jax.jit(self._embed_tokens)
         self._logits = jax.jit(self._head_logits)
 
-    def _weights_of_layer(self, key, layer):
-        w = weights.layer_weights(self.spec, key, layer)
+    def _weights_of_layer(self, key, layer, shapes):
+        w = weights.layer_weights(dict(shapes), key, layer,
+                                  self.expert_roles)
         w = {r: a.astype(jnp.float32) for r, a in w.items()}
         if self.quant is not None:
-            w = {r: quantize(a, 0, self.quant) if r.endswith(".w") else a
+            w = {r: quantize(a, -2, self.quant) if r.endswith(".w") else a
                  for r, a in w.items()}
         return w
 
@@ -157,7 +151,7 @@ class Reference:
         fn = weights.draw(key, "final_norm", weights.NO_LAYER, (d,),
                           jnp.bfloat16)
         h = x.reshape(-1, d)[flat_idx]
-        h = _rmsnorm(h, fn.astype(jnp.float32), spec["norm_eps"])
+        h = rmsnorm(h, fn.astype(jnp.float32), spec["norm_eps"])
         head = self._table(key, "embed" if spec["tie_word_embeddings"]
                            else "lm_head")
         if self.quant is not None:
@@ -184,7 +178,8 @@ class Reference:
             blocks = [x[i:i + self.block]
                       for i in range(0, self.rows, self.block)]
             for layer in range(spec["num_hidden_layers"]):
-                w = self._layer_w(self.key, jnp.int32(layer))
+                shapes = tuple(self.layer_shapes(spec, layer).items())
+                w = self._layer_w(self.key, jnp.int32(layer), shapes)
                 blocks = [self._layer(w, xb) for xb in blocks]
                 del w
             x = jnp.concatenate(blocks)

@@ -14,9 +14,10 @@ chip's idle time by span (and chip 0's by the innermost host event,
 JAX's calls included), ``device_idle_host`` (the share of the slice
 in which no operation ran on the chip while a scheduler span other than
 the wait for work was open, mean over chips), the medians of each
-request's wait for the frontend's mutex and in the admission queue over
-the requests due in the window, the traced window's end-to-end numbers,
-and what a span costs on this host with the profiler off and on.  The
+request's wait in ``submit`` for the frontend's bookkeeping lock (which
+no engine step holds) and in the admission queue over the requests due
+in the window, the traced window's end-to-end numbers, and what a span
+costs on this host with the profiler off and on.  The
 last line of standard output is one JSON object; ``--out`` writes it to
 a file too.
 """

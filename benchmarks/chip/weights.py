@@ -5,15 +5,20 @@ Each weight is named by a role (``q.w``, ``up.b``, ``norm1``, ``embed``,
 ...) and a layer.  Row ``r`` of a matrix is drawn from a key folded from
 the seed, the role, the layer and ``r``, so its values do not depend on
 how many rows the holder keeps (a padded vocabulary), how the layers are
-stacked or how the matrix is sharded.  The program's parameter tree is
-filled by role through :func:`program_params`; the reference draws one
-layer at a time through :func:`layer_weights`.
+stacked or how the matrix is sharded.  A role that a block marks as
+expert-stacked leads with an expert axis: expert ``e`` is a matrix of its
+own, drawn from the role's key folded with ``e``'s global index, so a
+holder of experts ``[e0, e0 + n)`` draws what the whole layer holds
+there.  Which leaf of the program's tree has which role, and which shape
+a layer's weights have, is the block's (``blocks/<name>.py``); the
+program's tree is filled through :func:`program_params`, and the
+reference draws one layer at a time through :func:`layer_weights`.
 """
 from __future__ import annotations
 
 import math
 import zlib
-from typing import Callable, Dict, Tuple
+from typing import Callable, Collection, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,10 +32,7 @@ BIAS_STD = 0.1
 
 # The "layer" of the weights outside the layers (embeddings, final norm).
 NO_LAYER = 0xFFFF
-
-LAYER_ROLES = ("norm1", "norm2", "q.w", "k.w", "v.w", "o.w", "q.b", "k.b",
-               "v.b", "o.b", "up.w", "gate.w", "down.w", "up.b", "gate.b",
-               "down.b")
+OUTER_ROLES = ("embed", "lm_head", "final_norm")
 
 
 def base_key(seed: int) -> jax.Array:
@@ -44,11 +46,8 @@ def _role_key(key: jax.Array, role: str, layer) -> jax.Array:
     return jax.random.fold_in(key, layer)
 
 
-def draw(key: jax.Array, role: str, layer, shape: Tuple[int, ...],
-         dtype) -> jax.Array:
-    """The weight ``role`` of ``layer`` (``NO_LAYER`` outside them), shaped
-    ``shape``: row ``r`` of a matrix from ``fold_in(key, r)``."""
-    k = _role_key(key, role, layer)
+def _sample(k: jax.Array, role: str, shape: Tuple[int, ...],
+            dtype) -> jax.Array:
     if len(shape) == 1:
         z = jax.random.normal(k, shape, jnp.float32)
         if role.startswith("norm") or role == "final_norm":
@@ -61,27 +60,56 @@ def draw(key: jax.Array, role: str, layer, shape: Tuple[int, ...],
     return (z * np.float32(1.0 / math.sqrt(fan_in))).astype(dtype)
 
 
+def draw(key: jax.Array, role: str, layer, shape: Tuple[int, ...],
+         dtype) -> jax.Array:
+    """The weight ``role`` of ``layer`` (``NO_LAYER`` outside them), shaped
+    ``shape``: row ``r`` of a matrix from ``fold_in(key, r)``."""
+    return _sample(_role_key(key, role, layer), role, shape, dtype)
+
+
+def draw_experts(key: jax.Array, role: str, layer, shape: Tuple[int, ...],
+                 dtype, first_expert=0) -> jax.Array:
+    """The expert-stacked weight ``role`` of ``layer``, shaped
+    ``(n, ...)``: experts ``first_expert ... first_expert + n - 1`` of the
+    layer, each drawn as :func:`draw` draws one weight of ``shape[1:]``
+    (a matrix's fan-in is its own input width), from the role's key
+    folded with the expert's global index."""
+    k = _role_key(key, role, layer)
+    experts = first_expert + jnp.arange(shape[0])
+    return jax.vmap(lambda e: _sample(jax.random.fold_in(k, e), role,
+                                      shape[1:], dtype))(experts)
+
+
+def draw_role(key: jax.Array, role: str, layer, shape: Tuple[int, ...],
+              dtype, expert_roles: Collection[str] = (),
+              first_expert=0) -> jax.Array:
+    """:func:`draw_experts` for a role in ``expert_roles``, else
+    :func:`draw`."""
+    if role in expert_roles:
+        return draw_experts(key, role, layer, shape, dtype, first_expert)
+    return draw(key, role, layer, shape, dtype)
+
+
 # --------------------------------------------------------------------------
 # The program's tree, filled by role
 # --------------------------------------------------------------------------
-def role_of(path) -> str:
+def role_of(path, block) -> str:
     """Role of a leaf of the program's parameter tree, from its path
-    (``groups/0/b0/mixer/q/w`` -> ``q.w``); an unknown leaf is an error,
-    so a change of the program's tree cannot pass unseen."""
+    (``groups/0/b0/mixer/q/w`` -> ``q.w``): the weights outside the
+    layers here, a layer's leaves by ``block.role`` of the path under the
+    layer.  An unknown leaf is an error, so a change of the program's tree
+    cannot pass unseen."""
     keys = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
-    last = keys[-2:]
     if keys[0] in ("embed", "lm_head") and keys[-1] == "table":
         return keys[0]
     if keys == ["final_norm", "scale"]:
         return "final_norm"
-    if keys[0] == "groups" and last[-1] == "scale" and last[0] in (
-            "norm1", "norm2"):
-        return last[0]
-    if keys[0] == "groups" and last[-1] in ("w", "b"):
-        role = f"{last[0]}.{last[1]}"
-        if role in LAYER_ROLES:
+    if keys[0] == "groups":
+        role = block.role(keys[3:])
+        if role is not None:
             return role
-    raise KeyError(f"no role for parameter {'/'.join(keys)}")
+    raise KeyError(f"no role for parameter {'/'.join(keys)} in block "
+                   f"{block.__name__}")
 
 
 def _layer_index(path, group_offsets, period) -> Callable:
@@ -93,11 +121,13 @@ def _layer_index(path, group_offsets, period) -> Callable:
     return lambda r: group_offsets[g] + r * period[g] + i
 
 
-def program_params(shapes_tree, seed: int, out_shardings=None):
+def program_params(shapes_tree, seed: int, block, out_shardings=None,
+                   first_expert: int = 0):
     """Fill the program's parameter tree (``jax.eval_shape`` of its
     ``init_params``) from ``seed``, as one jitted call on the device,
     each leaf in its own dtype and, with ``out_shardings``, straight onto
-    its shards."""
+    its shards.  ``block`` names each leaf's role; an expert-stacked
+    leaf holds the layer's experts from ``first_expert`` on."""
     groups = shapes_tree.get("groups", [])
     period = [len(g) for g in groups]
     reps = [jax.tree.leaves(g)[0].shape[0] for g in groups]
@@ -106,13 +136,14 @@ def program_params(shapes_tree, seed: int, out_shardings=None):
 
     def fill(key):
         def leaf(path, sd):
-            role = role_of(path)
-            if role in ("embed", "lm_head", "final_norm"):
+            role = role_of(path, block)
+            if role in OUTER_ROLES:
                 return draw(key, role, NO_LAYER, sd.shape, sd.dtype)
             layer = _layer_index(path, offsets, period)
             rs = jnp.arange(sd.shape[0])
-            return jax.vmap(lambda r: draw(key, role, layer(r),
-                                           sd.shape[1:], sd.dtype))(rs)
+            return jax.vmap(lambda r: draw_role(
+                key, role, layer(r), sd.shape[1:], sd.dtype,
+                block.EXPERT_ROLES, first_expert))(rs)
         return jax.tree_util.tree_map_with_path(leaf, shapes_tree)
 
     return jax.jit(fill, out_shardings=out_shardings)(base_key(seed))
@@ -121,24 +152,12 @@ def program_params(shapes_tree, seed: int, out_shardings=None):
 # --------------------------------------------------------------------------
 # One layer at a time, for the reference
 # --------------------------------------------------------------------------
-def layer_shapes(spec: dict) -> Dict[str, Tuple[int, ...]]:
-    d, hd, ff = spec["hidden_size"], spec["head_dim"], spec["intermediate_size"]
-    h, hkv = spec["num_attention_heads"], spec["num_key_value_heads"]
-    shapes = {"norm1": (d,), "norm2": (d,), "q.w": (d, h * hd),
-              "k.w": (d, hkv * hd), "v.w": (d, hkv * hd), "o.w": (h * hd, d),
-              "up.w": (d, ff), "down.w": (ff, d)}
-    if spec["gated_mlp"]:
-        shapes["gate.w"] = (d, ff)
-    if spec["use_bias"]:
-        for role, shp in list(shapes.items()):
-            if role.endswith(".w"):
-                shapes[role[:-2] + ".b"] = (shp[1],)
-    return shapes
-
-
-def layer_weights(spec: dict, key: jax.Array, layer,
+def layer_weights(shapes: Dict[str, Tuple[int, ...]], key: jax.Array, layer,
+                  expert_roles: Collection[str] = (), first_expert: int = 0,
                   dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
-    """Layer ``layer``'s weights as the program holds them (``dtype``),
-    for the reference to upcast."""
-    return {role: draw(key, role, layer, shp, dtype)
-            for role, shp in layer_shapes(spec).items()}
+    """Layer ``layer``'s weights of ``shapes`` (a block's
+    ``layer_shapes``), as the program holds them (``dtype``), for the
+    reference to upcast."""
+    return {role: draw_role(key, role, layer, shp, dtype, expert_roles,
+                            first_expert)
+            for role, shp in shapes.items()}

@@ -8,7 +8,7 @@ from benchmarks.chip import harness
 
 def spec_of(cfg) -> dict:
     """The configuration file a smoke config would have."""
-    return {"name": cfg.name, "arch": cfg.name,
+    return {"name": cfg.name, "arch": cfg.name, "block": "dense",
             "num_hidden_layers": cfg.n_layers, "hidden_size": cfg.d_model,
             "num_attention_heads": cfg.n_heads,
             "num_key_value_heads": cfg.n_kv_heads,

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from chipbench_smoke import deep_cell, smoke_cell, spec_of
 
-from benchmarks.chip import harness, reference, weights
+from benchmarks.chip import harness, weights
 
 
 @pytest.mark.parametrize("arch", ["yi-6b", "granite-20b"])
@@ -27,11 +27,12 @@ def test_program_tree_and_reference_layers_draw_the_same_weights(arch):
     spec = spec_of(cfg)
     shapes = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.random.PRNGKey(0))
-    params = weights.program_params(shapes, 2**33 + 5)
+    block = harness.block_of(spec)
+    params = weights.program_params(shapes, 2**33 + 5, block)
     key = weights.base_key(2**33 + 5)
     seen = set()
     for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
-        role = weights.role_of(path)
+        role = weights.role_of(path, block)
         seen.add(role)
         if role in ("embed", "lm_head", "final_norm"):
             want = weights.draw(key, role, weights.NO_LAYER,
@@ -39,10 +40,11 @@ def test_program_tree_and_reference_layers_draw_the_same_weights(arch):
             np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want))
             continue
         for layer in range(cfg.n_layers):
-            want = weights.layer_weights(spec, key, layer)[role]
+            want = weights.layer_weights(block.layer_shapes(spec, layer),
+                                         key, layer)[role]
             np.testing.assert_array_equal(np.asarray(leaf[layer]),
                                           np.asarray(want))
-    assert seen >= set(weights.layer_shapes(spec)) | {"embed", "final_norm"}
+    assert seen >= set(block.layer_shapes(spec, 0)) | {"embed", "final_norm"}
     # A padded vocabulary holds the reference's rows unchanged.
     table = params["embed"]["table"]
     short = weights.draw(key, "embed", weights.NO_LAYER,
@@ -63,7 +65,7 @@ def test_reference_matches_the_program_forward_in_float32():
     spec = spec_of(cfg)
     shapes = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.random.PRNGKey(0))
-    params = weights.program_params(shapes, 11)
+    params = weights.program_params(shapes, 11, harness.block_of(spec))
     # The reference rounds its weights to bfloat16 as the cells serve
     # them; give the program the same values.
     params = jax.tree.map(
@@ -74,7 +76,8 @@ def test_reference_matches_the_program_forward_in_float32():
     with jax.default_matmul_precision("highest"):
         got, _ = forward_prefill(params, cfg, {"tokens": jnp.asarray(toks)},
                                  logits_index=jnp.asarray(idx))
-    ref = reference.Reference(spec, 11, rows=2, seq_len=24, block=1)
+    ref = harness.block_of(spec).Reference(spec, 11, rows=2, seq_len=24,
+                                           block=1)
     want = ref.logits(list(toks), [np.arange(24)] * 2, 48)
     got = np.asarray(got)[:, :, :cfg.vocab_size].reshape(48, -1)
     np.testing.assert_allclose(got, np.asarray(want), atol=2e-4, rtol=2e-4)

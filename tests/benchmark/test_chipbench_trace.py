@@ -79,7 +79,8 @@ def test_the_step_shares_of_peak_by_hand():
     ctx = harness.LayerContext(
         spec=spec, chips=1, tp=1, peaks=peaks_of("TPU v5 lite"),
         devices=[], slice_s=1.0, stats0={}, stats1={},
-        decode_contexts=[100, 200], prompt_lens=[300], memory_peak_bytes=0)
+        decode_contexts=[100, 200], prompt_lens=[300], memory_peak_bytes=0,
+        block=harness.block_of(spec))
     layers = 2 * 32 * (4096 * 32 * 128 * 2 + 4096 * 4 * 128 * 2
                        + 3 * 4096 * 11008)
     head = 2 * 4096 * 64000
