@@ -8,8 +8,10 @@ reference and its operation counts) by the name its file gives under
 program under test is driven through its public serving path:
 ``make_engine(kind="paged")`` -> ``ServeFrontend.warmup`` ->
 ``ServeFrontend.submit``.  The benchmark stamps when each request was
-due and when each of its tokens was delivered (``on_token``), and reads
-the engine's counters as differences over the traced slice.
+due and when each of its tokens was delivered (``on_token``), reads the
+engine's counters as differences over the traced slice, and judges the
+tokens its handles hold, with the logit each was served by where the
+program delivers one (``RequestHandle.token_logits``).
 """
 from __future__ import annotations
 
@@ -224,7 +226,8 @@ def prime(fe, traffic: dict, vocab: int) -> None:
 # --------------------------------------------------------------------------
 class Rec:
     """One request as the benchmark sees it: when it was due, when it
-    was sent, and when each token was delivered (host clock)."""
+    was sent, when each token was delivered (host clock), and what its
+    handle holds."""
 
     __slots__ = ("req", "due", "sent", "times", "handle", "on_last")
 
@@ -242,6 +245,12 @@ class Rec:
 
     def tokens(self) -> np.ndarray:
         return np.asarray(self.handle.tokens, np.int32)
+
+    def logits(self) -> Optional[np.ndarray]:
+        """The logit the program picked each token by, or ``None`` from
+        a program whose handles deliver none."""
+        got = getattr(self.handle, "token_logits", None)
+        return None if got is None else np.asarray(got, np.float32)
 
     def ok(self) -> bool:
         return (self.handle is not None and self.handle.done
@@ -544,13 +553,15 @@ def breakdown(devs: List[xplane.DeviceTrace], slice_ns: float) -> dict:
 # --------------------------------------------------------------------------
 # The check against the reference
 # --------------------------------------------------------------------------
-Sample = List[tuple]   # (prompt, served tokens), plain int32 arrays
+# (prompt, served tokens, served logits or None): plain numpy arrays.
+Sample = List[tuple]
 
 
 def check_sample(win: Window, cell: Cell, seed: int) -> Sample:
     """Requests due in the window that finished, drawn from the seed,
     with the one that served most tokens and the one with the longest
-    prompt among them, copied out of the program's handles."""
+    prompt among them, copied out of the program's handles with the
+    logits they were served by."""
     k = cell.traffic["check"]["requests"]
     done = [r for r in win.recs if r.due < win.t1 and r.ok()]
     chosen = set(range(len(done)))
@@ -563,8 +574,25 @@ def check_sample(win: Window, cell: Cell, seed: int) -> Sample:
             if len(chosen) >= k:
                 break
             chosen.add(int(i))
-    return [(np.array(done[i].req.prompt, np.int32), done[i].tokens())
-            for i in sorted(chosen)]
+    return [(np.array(done[i].req.prompt, np.int32), done[i].tokens(),
+             done[i].logits()) for i in sorted(chosen)]
+
+
+def served_logits(sample: Sample) -> Optional[np.ndarray]:
+    """The logit served with each token of ``sample``, in the order of
+    :func:`reference.teacher_forced`; ``None`` where the program delivers
+    none.  A request whose handle holds another number of logits than of
+    tokens reads NaN at each of its positions, which no limit admits."""
+    if all(lg is None for _, _, lg in sample):
+        return None
+    out = []
+    for _, toks, lg in sample:
+        if lg is None or len(lg) != len(toks):
+            log(f"[check] a request served {len(toks)} tokens with "
+                f"{'no' if lg is None else len(lg)} logits")
+            lg = np.full(len(toks), np.nan, np.float32)
+        out.append(lg)
+    return np.concatenate(out)
 
 
 def reference_logits(cell: Cell, seed: int, sample: Sample, device,
@@ -669,37 +697,75 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, *, cfg,
     return line, sample, ref_device
 
 
+def _worst(errs: np.ndarray) -> float:
+    """The largest of ``errs``; NaN, a logit missing, reads infinite."""
+    top = float(np.max(errs)) if len(errs) else 0.0
+    return math.inf if math.isnan(top) else top
+
+
 def check(cell: Cell, seed: int, line: dict, sample: Sample, device,
           control: Optional[str] = None) -> dict:
-    """Compare the served tokens of ``sample`` with the reference and add
-    ``correct`` and the numbers compared (``checks``, last) to ``line``.
+    """Compare what the program served in ``sample`` with the float32
+    reference, teacher-forced over each prompt and its served tokens, and
+    add ``correct`` and the numbers compared (``checks``, last) to
+    ``line``.  ``correct`` needs every number compared within its limit:
 
-    ``control`` puts the reference at a lower precision (``"int8"``) in
-    the program's place: at each served position the token it ranks
-    first is judged instead of the served one."""
-    from benchmarks.chip.reference import gaps_of, top_tokens
+    - ``logit_gap``: the widest gap by which the reference's logit of a
+      served token lies below the reference's best there (limit
+      ``check.gap_limit`` of the traffic file);
+    - ``logit_err``: the widest distance between the logit the program
+      served a token with and the reference's logit of that token (limit
+      ``check.logit_limit``).  With tied embeddings and seeded weights
+      the input token tops the logits, so wrong arithmetic can still
+      serve the reference's tokens; it cannot serve its logits.  It is
+      compared only where the program's handles deliver logits and the
+      traffic file states the limit; otherwise a line on standard error
+      says that it is not, and the rest decide;
+    - ``failed_requests``: requests due in the window that did not
+      finish (limit 0).
 
+    ``control`` puts the reference at a lower precision (``"fp8"``,
+    ``"int8"``) in the program's place: at each served position the token
+    it ranks first, with its own logit there, is judged instead of the
+    served one.  It is judged on the numbers the program's run is judged
+    on: where the program delivers no logits, the control's ``logit_err``
+    is logged and not compared."""
+    from benchmarks.chip.reference import errs_of, gaps_of, top_picks
+
+    chk = cell.traffic["check"]
     t0 = time.perf_counter()
+    gap, err, toks = math.inf, None, []
     if sample:
         logits, toks = reference_logits(cell, seed, sample, device)
-        gap = float(np.max(gaps_of(logits, toks)))
+        served = delivered = served_logits(sample)
         if control is not None:
-            ctrl, _ = reference_logits(cell, seed, sample, device,
-                                       quant=control)
-            picks = top_tokens(ctrl, len(toks))
+            ctrl, _ = reference_logits(cell, seed, sample, device, control)
+            picks, picked = top_picks(ctrl, len(toks))
             del ctrl
+            own = ("none delivered" if delivered is None else
+                   repr(_worst(errs_of(logits, toks, delivered))))
             log(f"[check] control {control}: {int(np.sum(picks != toks))}"
                 f" of {len(toks)} tokens differ from the served ones; the "
-                f"program's own logit_gap {gap!r}")
-            gap = float(np.max(gaps_of(logits, picks)))
+                "program's own logit_gap "
+                f"{float(np.max(gaps_of(logits, toks)))!r}, logit_err {own}")
+            toks, served = picks, picked
+        gap = float(np.max(gaps_of(logits, toks)))
+        if served is not None:
+            err = _worst(errs_of(logits, toks, served))
         del logits
-    else:
-        gap, toks = math.inf, []
+        if delivered is None or "logit_limit" not in chk:
+            why = ("the program delivers no logits" if delivered is None
+                   else "the traffic file states no check.logit_limit")
+            read = "" if err is None else f" {err!r}"
+            log(f"[check] logit_err{read} is not compared ({why}); "
+                "logit_gap decides")
+            err = None
     log(f"[check] reference over {len(sample)} requests, {len(toks)} "
         f"served tokens: {time.perf_counter() - t0:.2f} s")
-    checks = {"logit_gap": {"value": gap,
-                            "limit": cell.traffic["check"]["gap_limit"]},
-              "failed_requests": {"value": line["failed"], "limit": 0}}
+    checks = {"logit_gap": {"value": gap, "limit": chk["gap_limit"]}}
+    if err is not None:
+        checks["logit_err"] = {"value": err, "limit": chk["logit_limit"]}
+    checks["failed_requests"] = {"value": line["failed"], "limit": 0}
     out = {"correct": all(c["value"] <= c["limit"]
                           for c in checks.values())}
     out.update(line)

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -195,17 +195,30 @@ def gaps_of(ref_logits: jax.Array, tokens: np.ndarray) -> np.ndarray:
     return np.asarray(best - got)
 
 
-def top_tokens(logits: jax.Array, n: int) -> np.ndarray:
-    """The token ranked first at each of the first ``n`` positions."""
-    return np.asarray(jnp.argmax(logits[:n], axis=-1), np.int32)
+def errs_of(ref_logits: jax.Array, tokens: np.ndarray,
+            served: np.ndarray) -> np.ndarray:
+    """Per position: how far the logit served with the token chosen there
+    lies from the reference's logit of that token, either way."""
+    lg = ref_logits[:len(tokens)]
+    got = jnp.take_along_axis(lg, jnp.asarray(tokens)[:, None], -1)[:, 0]
+    return np.abs(np.asarray(got) - np.asarray(served, np.float32))
 
 
-def teacher_forced(sample: List[Tuple[np.ndarray, np.ndarray]]):
+def top_picks(logits: jax.Array, n: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The token ranked first at each of the first ``n`` positions, and
+    its logit there."""
+    lg = logits[:n]
+    picks = jnp.argmax(lg, axis=-1)
+    vals = jnp.take_along_axis(lg, picks[:, None], -1)[:, 0]
+    return np.asarray(picks, np.int32), np.asarray(vals, np.float32)
+
+
+def teacher_forced(sample: Sequence[tuple]):
     """(sequence fed, positions read, tokens served) of each
-    ``(prompt, served)`` pair: the reference reads position
+    ``(prompt, served, ...)`` entry: the reference reads position
     ``len(prompt) - 1 + j`` for served token ``j``."""
     seqs, pos, toks = [], [], []
-    for prompt, served in sample:
+    for prompt, served, *_ in sample:
         seqs.append(np.concatenate([prompt, served[:-1]]).astype(np.int32))
         pos.append(len(prompt) - 1 + np.arange(len(served)))
         toks.append(np.asarray(served, np.int32))

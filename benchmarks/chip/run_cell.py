@@ -5,14 +5,17 @@
 
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics from a profiler trace of a steady slice of the window.
-Every run checks what it served against the float32 reference.  The last
+Every run checks what it served, the tokens and, where the program
+delivers them, the logits they were served by, against the float32
+reference (``harness.check``).  The last
 line of standard output is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, ``breakdown`` when traced, and the
 numbers compared beside their limits under ``checks``); the last lines of
-standard error are those numbers again.  ``--control int8`` runs the
-same, but judges the float32 reference's int8 twin in the program's
-place, which has to come out not correct.  A run that finds no TPU, or
-fewer chips than the cell asks for, exits 2 and prints no result.
+standard error are those numbers again.  ``--control fp8`` (or ``int8``)
+runs the same, but judges the float32 reference's float8 (int8) twin in
+the program's place, which has to come out not correct.  A run that
+finds no TPU, or fewer chips than the cell asks for, exits 2 and prints
+no result.
 """
 from __future__ import annotations
 

@@ -20,8 +20,14 @@ Keys (all lengths in tokens, times in seconds):
 - ``prompt_len``, ``output_len``: ``{"dist": "lognormal", "median",
   "sigma", "min", "max"}`` or ``{"dist": "uniform", "min", "max"}``.
 - ``engine``: ``max_slots``, ``max_seq``, ``page_size``.
-- ``check``: the requests the reference reads, in blocks of ``block``,
-  and the limit of the widest logit gap (``harness.check``).
+- ``check``: the requests the reference reads (``requests``), in blocks
+  of ``block``; ``gap_limit``, the limit of the widest gap of a served
+  token's reference logit below the reference's best; ``logit_limit``
+  (optional), the limit of the widest distance between the logit a
+  token was served with and the reference's logit of that token
+  (``harness.check``), which is not compared where the mix states none.
+  A limit lies between the largest reading of sound runs of the program
+  and the smallest of the float8 control on the chip (``PERF.md``).
 """
 from __future__ import annotations
 

@@ -39,15 +39,17 @@ def smoke_cell(arch: str = "yi-6b", cell: str = "yi6b-chat"):
     return c, cfg
 
 
-def deep_cell():
+def deep_cell(arch: str = "yi-6b", **widths):
     """The cell at yi-6b's depth (32 layers) and vocabulary (64000) but a
     width of 256: rounding compounds over the layers and ranks among the
     vocabulary as at the published size, so the check separates the
-    program from the control as it does on the chip."""
-    c, cfg = smoke_cell()
-    cfg = dataclasses.replace(cfg, n_layers=32, d_model=256, n_heads=8,
-                              n_kv_heads=2, head_dim=32, d_ff=704,
-                              vocab_size=64000)
+    program from the control as it does on the chip.  ``arch`` picks the
+    family, ``widths`` its heads and MLP."""
+    c, cfg = smoke_cell(arch)
+    widths = dict(dict(n_heads=8, n_kv_heads=2, head_dim=32, d_ff=704),
+                  **widths)
+    cfg = dataclasses.replace(cfg, n_layers=32, d_model=256,
+                              vocab_size=64000, **widths)
     c.spec = spec_of(cfg)
     c.traffic = dict(
         c.traffic, rate_per_s=3.0,
@@ -57,3 +59,12 @@ def deep_cell():
         engine={"max_slots": 4, "max_seq": 128, "page_size": 16},
         check=dict(c.traffic["check"], requests=4, block=4))
     return c, cfg
+
+
+def tied_deep_cell():
+    """The deep cell of the tied ``granite-20b`` family (one KV head, a
+    plain GELU MLP of four times the width, biases, the embedding table
+    as the head): the residual stream keeps the scaled embedding of the
+    input token, and the head scores that same row, so the input token
+    tops the logits."""
+    return deep_cell("granite-20b", n_kv_heads=1, d_ff=1024)

@@ -117,6 +117,65 @@ def test_sound_run_is_correct(served):
     assert list(out)[-1] == "checks"
 
 
+# A limit for the mechanism below, not one set from readings: the logits
+# handed over are the reference's own, or off by twice this.
+LOGIT_LIMIT = 0.25
+
+
+def _with_logits(cell, seed, sample, how):
+    """``sample`` as a program that delivers logits would hand it over:
+    the float32 reference's logit of each served token, then ``how``
+    breaks it (``none_delivered``: no logits at all, as from a program
+    that delivers none)."""
+    ref, toks = harness.reference_logits(cell, seed, sample,
+                                         jax.devices()[0])
+    vals = np.asarray(jnp.take_along_axis(
+        ref[:len(toks)], jnp.asarray(toks)[:, None], -1)[:, 0])
+    at = np.cumsum([0] + [len(t) for _, t, _ in sample])
+    out = [(p, t, vals[at[i]:at[i + 1]].copy())
+           for i, (p, t, _) in enumerate(sample)]
+    if how == "one_off":
+        out[0][2][1] += 2 * LOGIT_LIMIT
+    elif how == "one_missing":
+        out[-1] = (out[-1][0], out[-1][1], out[-1][2][:-1])
+    elif how == "none_delivered":
+        out = [(p, t, None) for p, t, _ in out]
+    return out
+
+
+@pytest.mark.parametrize("how", ["exact", "one_off", "one_missing",
+                                 "none_delivered", "no_limit"])
+def test_served_logits_are_judged_by_logit_err(served, how, capsys):
+    """A served logit off by more than the limit, or a token served
+    without its logit, fails ``logit_err``; a program that delivers no
+    logits, or a mix that states no ``logit_limit``, is judged by
+    ``logit_gap`` alone, and the run says so."""
+    cell, cfg, s = served
+    if how != "no_limit":
+        chk = dict(cell.traffic["check"], logit_limit=LOGIT_LIMIT)
+        cell = dataclasses.replace(cell, traffic=dict(cell.traffic, check=chk))
+    win = harness.run_window(s, cell, 5, 2.0, cfg.vocab_size, drain_s=30)
+    e2e = harness.end_to_end(win)
+    line = {"attempted": e2e["attempted"], "failed": e2e["failed"]}
+    sample = _with_logits(cell, 2**31 + 77, harness.check_sample(win, cell, 5),
+                          how)
+    out = harness.check(cell, 2**31 + 77, line, sample, jax.devices()[0])
+    unjudged = how in ("none_delivered", "no_limit")
+    assert list(out["checks"]) == (
+        ["logit_gap", "failed_requests"] if unjudged
+        else ["logit_gap", "logit_err", "failed_requests"])
+    assert out["correct"] == (how in ("exact", "none_delivered", "no_limit"))
+    err = capsys.readouterr().err
+    if how == "none_delivered":
+        assert "delivers no logits" in err
+    elif how == "no_limit":
+        assert "states no check.logit_limit" in err
+    else:
+        err = out["checks"]["logit_err"]
+        assert (err["value"] < 1e-4) if how == "exact" \
+            else (err["value"] > err["limit"])
+
+
 def _wrap_window(monkeypatch, eng, fault):
     orig = eng._window_fn
 
@@ -186,7 +245,7 @@ def test_the_fp8_control_is_not_correct():
         s.frontend.shutdown(drain=False)
     line = {"attempted": e2e["attempted"], "failed": e2e["failed"]}
     sample = harness.check_sample(win, cell, 46)
-    assert e2e["failed"] == 0 and sum(len(t) for _, t in sample) > 80
+    assert e2e["failed"] == 0 and sum(len(t) for _, t, _ in sample) > 80
     sound = harness.check(cell, 5, line, sample, jax.devices()[0])
     ctrl = harness.check(cell, 5, line, sample, jax.devices()[0], "fp8")
     assert sound["correct"], sound["checks"]

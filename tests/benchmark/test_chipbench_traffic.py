@@ -108,3 +108,12 @@ def test_every_seed_has_the_same_requests_due_in_the_window(name):
                      sorted(r.max_new_tokens for r in due)))
     assert seen[0][0] == round(OPEN[name]["rate_per_s"] * 50.0)
     assert seen[0] == seen[1] == seen[2]
+
+
+@pytest.mark.parametrize("name", sorted(TRAFFIC))
+def test_every_mix_states_its_check(name):
+    """The requests the reference reads, the gap limit, and the logit
+    limit where the mix states one (``harness.check``)."""
+    chk = TRAFFIC[name]["check"]
+    assert chk["requests"] % chk["block"] == 0
+    assert chk["gap_limit"] > 0 and chk.get("logit_limit", 1) > 0
