@@ -66,8 +66,7 @@ def main():
 
     # Same workload on the ladder-locked fast path: slot cache, fixed
     # SLAB_LADDER decode shapes, on-device multi-token windows.
-    slot = make_engine(cfg, params, kind="slot", max_slots=8, max_seq=96,
-                       window=8)
+    slot = make_engine(cfg, params, kind="slot", max_slots=8, max_seq=96)
     rng = np.random.default_rng(0)
     for i, L in enumerate(lengths):
         slot.submit(Request(
@@ -105,7 +104,7 @@ def main():
     # pool is 0.375x that.  Tokens must be identical to the slot
     # engine on any workload — rows are independent in both.
     paged = make_engine(cfg, params, kind="paged", max_slots=8,
-                        max_seq=96, window=8, page_size=12, num_pages=24)
+                        max_seq=96, page_size=12, num_pages=24)
     rng = np.random.default_rng(0)
     for i, L in enumerate(lengths):
         paged.submit(Request(
@@ -133,7 +132,7 @@ def main():
     # streaming handles, same-bucket arrivals coalesced into batched
     # prefills, AOT warmup so steady state never compiles.
     fresh = make_engine(cfg, params, kind="slot", max_slots=8,
-                        max_seq=96, window=8)
+                        max_seq=96)
     fe = ServeFrontend(fresh)
     t0 = time.time()
     fe.warmup(max_prompt_len=64)

@@ -78,8 +78,9 @@ class Completion:
 
     ``tokens`` is the full greedy stream (prefill's first token
     included); ``ttft`` is seconds from submission to the first token;
-    ``tpot`` is mean seconds per subsequent token (window-granular for
-    the slot engines — the host observes tokens once per window);
+    ``tpot`` is mean seconds per subsequent token (on the slot engines
+    the host reads tokens back once per decode window of ``window``
+    steps, so a stream arrives in chunks of that many);
     ``finish_reason`` is one of ``"length"`` (budget exhausted),
     ``"max_seq"`` (sequence capacity), ``"aborted"``.
     """
@@ -114,7 +115,13 @@ class EngineOptions:
     """Every serving-engine knob, in one frozen record.
 
     ``max_slots`` is the concurrent decode-row capacity (the dense
-    engines' ``max_batch``); ``ladder`` overrides the ``SLAB_LADDER``
+    engines' ``max_batch``); ``window`` is the decode steps of one
+    slot-engine window program (``None``:
+    :func:`~repro.serve.slot_engine.default_window`, one on an
+    accelerator — the engines keep the next window queued on the device
+    while the host reads the last one, so a short window costs no host
+    gap, and a new prompt's prefill waits behind at most one queued
+    window); ``ladder`` overrides the ``SLAB_LADDER``
     decode rungs (``None`` keeps the paper's ladder); ``buckets``
     selects prefill padding (``"auto"`` — powers of two on the slot
     engine, page multiples on the paged engine, exact lengths on the
@@ -128,7 +135,7 @@ class EngineOptions:
     """
     max_slots: int = 8
     max_seq: int = 256
-    window: int = 8
+    window: Optional[int] = None
     ladder: Optional[Tuple[int, ...]] = None
     buckets: str = "auto"
     page_size: int = 16

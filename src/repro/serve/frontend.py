@@ -16,10 +16,13 @@ needs: an always-on service wrapping one engine, with
   multi-prompt prefill-insert per bucket*
   (:meth:`~repro.serve.slot_engine.SlotServeEngine.prefill_batch`) so a
   burst of k arrivals costs one ``(rung, bucket)`` prefill call instead
-  of k, (2) drives one engine ``step()`` — one decode window — and
-  (3) flushes every newly generated token onto a backlog queue.  The
-  engine is never touched off this thread, so the engines stay
-  single-threaded internally.
+  of k, (2) drives one engine ``step()`` — it dispatches the next decode
+  window and reads back the one before, so the chip holds a window
+  while the host does the rest of the cycle — and (3) flushes every
+  newly generated token onto a backlog queue.  A prefill dispatched in
+  (1) queues behind at most the one window in flight.  The engine is
+  never touched off this thread, so the engines stay single-threaded
+  internally.
 
 * **Two locks**: the *engine lock* (``_mutex``) is held by the
   scheduler through all engine work — each prefill, decode window,
@@ -564,8 +567,18 @@ class ServeFrontend:
         if self.fault_plan is None or self._cycle == self._fault_cursor:
             return
         self._fault_cursor = self._cycle
-        for ev in self.fault_plan.events_at(self._cycle):
+        events = self.fault_plan.events_at(self._cycle)
+        if events:
+            self._drain_engine("fault")
+        for ev in events:
             self._apply_fault(ev)
+
+    def _drain_engine(self, reason: str) -> None:
+        """Read back the engine's window in flight (engine lock held);
+        engines without a window pipeline have none."""
+        drain = getattr(self.engine, "drain_pipeline", None)
+        if drain is not None:
+            drain(reason)
 
     def _apply_fault(self, ev) -> None:
         eng = self.engine
@@ -649,6 +662,7 @@ class ServeFrontend:
                         self._wake.wait(self.idle_wait)
                     self._wake.clear()
         with self._mutex:
+            self._drain_engine("shutdown")
             self._heal_seized()
         if self._abort.is_set():
             self._abort_inflight()

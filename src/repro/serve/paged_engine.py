@@ -78,7 +78,7 @@ This module applies the SISA idea to serving memory:
   ``admit_cap`` keeps the ladder sweep from targeting a rung the pools
   cannot back.
 
-The serve loop, ladder quantization, multi-token window, bucketed
+The serve loop, ladder quantization, window pipeline, bucketed
 prefill, and coexec backfill are inherited from ``SlotServeEngine``
 unchanged; only storage and the decode step differ
 (:func:`repro.models.attention.paged_attn_decode_step` reads the global
@@ -113,7 +113,8 @@ from repro.models.attention import CACHE_QUANT
 from repro.models.transformer import init_cache
 from repro.serve.engine import effective_tokens, encoder_inputs, Request
 from repro.serve.serve_step import make_paged_decode_step
-from repro.serve.slot_engine import SlotServeEngine
+from repro.serve.slot_engine import (decode_body, default_window,
+                                     SlotServeEngine)
 
 PyTree = Any
 
@@ -916,7 +917,9 @@ class PagedServeEngine(SlotServeEngine):
         # covers window + one decode window + one page of slack, so a
         # column is only ever re-targeted once its old block is fully
         # behind every read of the coming window.
-        window_tokens = int(kw.get("window", 8))
+        if kw.get("window") is None:
+            kw["window"] = default_window()
+        window_tokens = kw["window"]
         if self._has_local:
             w = min(cfg.sliding_window, max_seq)
             self.local_ring = -(-(w + window_tokens) // page_size) + 1
@@ -1217,6 +1220,9 @@ class PagedServeEngine(SlotServeEngine):
         # local rings past dead blocks.  Shared pages never overlap
         # write positions in the serve flow (they cover full prompt
         # pages only), but ensure_writable keeps the invariant explicit.
+        # Runs while the window before is still in flight: the mirrors
+        # already stand where that window leaves the rows, and every
+        # table or pool update here is ordered after it on the device.
         ext = self.stats["engine"]
         for slot in range(rung):
             if self._req[slot] is None:
@@ -1244,7 +1250,7 @@ class PagedServeEngine(SlotServeEngine):
     def window_program_text(self, rung: int) -> str:
         """Compiled HLO text of the decode window at batch shape ``rung``
         over the current params, pools and page tables."""
-        z = jnp.zeros(rung, jnp.int32)
+        z = self._rows_to_device(np.zeros(self.max_batch, np.int32))
         return self._window_fn.lower(
             self.params, self.cache.pools, self.cache.tables(), z, z, z,
             rung=rung).compile().as_text()
@@ -1257,14 +1263,15 @@ class PagedServeEngine(SlotServeEngine):
 
         def decode_window(params, pools, tables, toks, pos, budget, *,
                           rung):
-            """T greedy tokens at batch shape ``rung``; one host sync.
+            """T greedy tokens at batch shape ``rung``.
 
-            Same carry discipline as the dense window: page pools ride
-            the carry full-size (pages are row-owned, so no rung
-            slicing; donated), recurrent slabs are sliced to the rung's
-            rows exactly like dense slot buffers and written back after
-            the scan, and the per-class page tables are sliced to the
-            rung's rows.  Frozen rows write their own (or, once
+            Same carry discipline as the dense window (``toks``/``pos``/
+            ``budget`` over every slot, the first ``rung`` advanced):
+            page pools ride the carry full-size (pages are row-owned, so
+            no rung slicing; donated), recurrent slabs are sliced to the
+            rung's rows exactly like dense slot buffers and written back
+            after the scan, and the per-class page tables are sliced to
+            the rung's rows.  Frozen rows write their own (or, once
             released, the sink) page/slab row — never storage another
             row owns.
             """
@@ -1278,27 +1285,21 @@ class PagedServeEngine(SlotServeEngine):
                                                         axis=1)),
                 pools)
 
-            def body(carry, _):
-                c, tk, ps, bd = carry
-                logits, c = decode_fn(params, c, tbls, tk[:, None], ps)
-                nxt = jnp.argmax(logits[:, -1, :vocab],
-                                 axis=-1).astype(jnp.int32)
-                live = bd > 0
-                emit = jnp.where(live, nxt, -1)
-                tk = jnp.where(live, nxt, tk)
-                ps = jnp.where(live, ps + 1, ps)
-                bd = jnp.where(live, bd - 1, bd)
-                bd = jnp.where(ps >= max_seq - 1, 0, bd)
-                return (c, tk, ps, bd), emit
-
-            (sub, toks, pos, budget), out = jax.lax.scan(
-                body, (carry0, toks, pos, budget), None, length=T)
+            rows = (toks[:rung], pos[:rung], budget[:rung])
+            (sub, *rows), out = jax.lax.scan(
+                decode_body(lambda c, tk, ps: decode_fn(params, c, tbls,
+                                                        tk, ps),
+                            vocab, max_seq),
+                (carry0, *rows), None, length=T)
             pools = _map_named(
                 lambda n, b, s: (s if n in _POOL_LEAF_NAMES
                                  else jax.lax.dynamic_update_slice_in_dim(
                                      b, s, 0, axis=1)),
                 pools, sub)
             pools = self._constrain_caches(pools)
+            toks, pos, budget = self._constrain_rows(tuple(
+                jax.lax.dynamic_update_slice_in_dim(full, r, 0, axis=0)
+                for full, r in zip((toks, pos, budget), rows)))
             return pools, toks, pos, budget, out
 
         donate = () if jax.default_backend() == "cpu" else (1,)

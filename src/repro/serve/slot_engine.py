@@ -22,12 +22,24 @@ serving loop is as ladder-shaped as the kernels:
   one warmup compile per rung there are zero recompiles for the rest of
   the serve — ``stats["decode_compiles"]`` tracks the jit cache.
 
-* **On-device multi-token window**: ``lax.scan`` over ``window`` tokens
-  with on-device greedy argmax, per-slot positions (short requests never
-  attend past their own length — the legacy engine forced
-  ``pos = max(positions)`` on every row), per-slot done flags, and
-  donated cache buffers.  The host syncs once per window instead of once
-  per token; co-exec prefill backfill runs at window boundaries.
+* **On-device decode window**: ``lax.scan`` over ``window`` tokens
+  (:func:`default_window` unless given) with on-device greedy argmax,
+  per-slot positions (short requests never attend past their own
+  length — the legacy engine forced ``pos = max(positions)`` on every
+  row), per-slot done flags, and donated cache buffers; co-exec
+  prefill backfill runs at window boundaries.
+
+* **Two-deep window pipeline**: each step dispatches window *j+1*
+  straight from window *j*'s device outputs, then reads *j* back, so
+  the chip holds the next window while the host posts the last one.
+  The host mirrors of positions and budgets advance at dispatch by the
+  scan body's own rule, so page mapping sees exactly what the window in
+  flight will write.  Anything that changes the row set or storage
+  (admission, preemption, cancellation, remesh, reset, injected
+  faults) first *drains* the pipeline: it reads the window in flight
+  back, and the next window starts from the host mirrors.  A row that
+  finishes in *j* rides *j+1* frozen (budget 0) and releases its
+  storage at *j*'s read-back.
 
 * **Bucketed prefill**: prompts pad to power-of-two buckets
   (:func:`repro.serve.serve_step.make_bucketed_prefill_step`) so
@@ -59,7 +71,8 @@ outputs are batch-invariant by construction.
 from __future__ import annotations
 
 from collections import deque
-from typing import Any, Callable, Deque, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Deque, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +105,65 @@ def jit_cache_entries(fn) -> Optional[int]:
     """
     size = getattr(fn, "_cache_size", None)
     return size() if callable(size) else None
+
+
+def default_window() -> int:
+    """Decode steps per window when none is given: one on an
+    accelerator, where a served model's decode step outlasts the host's
+    work at a window boundary, so the window queued behind the running
+    one keeps the device busy; eight on the CPU backend, where a step is
+    far shorter than that work and a one-step window would leave the
+    device waiting on the host for most of each step."""
+    return 8 if jax.default_backend() == "cpu" else 1
+
+
+def decode_body(step: Callable, vocab: int, max_seq: int):
+    """One greedy decode step over ``(cache, toks, pos, budget)`` — the
+    scan body of both engines' windows, around ``step(cache, toks, pos)
+    -> (logits, cache)``.  :func:`advance_rows` is its host twin for
+    ``pos``/``budget``; the two must agree."""
+
+    def body(carry, _):
+        c, tk, ps, bd = carry
+        logits, c = step(c, tk[:, None], ps)
+        nxt = jnp.argmax(logits[:, -1, :vocab], axis=-1).astype(jnp.int32)
+        live = bd > 0
+        emit = jnp.where(live, nxt, -1)
+        tk = jnp.where(live, nxt, tk)
+        ps = jnp.where(live, ps + 1, ps)
+        bd = jnp.where(live, bd - 1, bd)
+        bd = jnp.where(ps >= max_seq - 1, 0, bd)
+        return (c, tk, ps, bd), emit
+
+    return body
+
+
+def advance_rows(pos: np.ndarray, budget: np.ndarray, steps: int,
+                 max_seq: int) -> None:
+    """Advance host ``pos``/``budget`` rows in place by ``steps`` decode
+    steps, by the scan body's rule: a live row (budget > 0) moves one
+    position and spends one token, and a row reaching ``max_seq - 1``
+    stops."""
+    for _ in range(steps):
+        live = budget > 0
+        pos += live
+        budget -= live
+        budget[pos >= max_seq - 1] = 0
+
+
+class _Window(NamedTuple):
+    """A decode window dispatched and not yet read back: its index and
+    rung, its device outputs (``toks``/``pos``/``budget`` over every
+    slot, ``out`` of shape ``(T, rung)``), and the host mirrors of
+    ``pos``/``budget`` as they stand after it."""
+    idx: int
+    rung: int
+    toks: Any
+    pos: Any
+    budget: Any
+    out: Any
+    pos_after: np.ndarray
+    budget_after: np.ndarray
 
 
 class SlotKVCache:
@@ -175,14 +247,17 @@ class SlotServeEngine:
     Drop-in peer of :class:`~repro.serve.engine.ServeEngine` (same
     ``submit``/``run``/``stats`` surface, token-identical outputs) whose
     hot path is compile-stable: decode runs at fixed ``SLAB_LADDER``
-    rungs over slot buffers, generating ``window`` tokens per host sync.
+    rungs over slot buffers, generating ``window`` tokens per program,
+    with the next window queued on the device while the host reads the
+    last one back (module docs).
     """
 
     def __init__(self, cfg: ModelConfig, params, *,
                  prefill_fn: Optional[Callable] = None,
                  decode_fn: Optional[Callable] = None,
                  cache_init_fn: Optional[Callable] = None,
-                 max_batch: int = 8, max_seq: int = 256, window: int = 8,
+                 max_batch: int = 8, max_seq: int = 256,
+                 window: Optional[int] = None,
                  ladder: Optional[Sequence[int]] = None,
                  multi_tenant: bool = True,
                  prefill_bucketing: bool = True,
@@ -213,7 +288,7 @@ class SlotServeEngine:
         self.params = params
         self.max_batch = max_batch
         self.max_seq = max_seq
-        self.window = window
+        self.window = default_window() if window is None else window
         self.multi_tenant = multi_tenant
         self.stats = init_serve_stats(coexec_backend, expert_backend)
         self.stats["engine"].update(self._stats_extras())
@@ -264,16 +339,20 @@ class SlotServeEngine:
         self.cache = self._make_cache()
         # Per-slot host state (mirrors the device-side window carries).
         self._req: List[Optional[Request]] = [None] * max_batch
+        # ``_pos``/``_budget`` run one window ahead of the last read-back:
+        # they advance when a window is dispatched.  ``_tok`` is exact
+        # whenever nothing is in flight, the only time it is read.
         self._tok = np.zeros(max_batch, np.int32)
         self._pos = np.zeros(max_batch, np.int32)
         self._budget = np.zeros(max_batch, np.int32)
+        self._inflight: Optional[_Window] = None
 
         self.queue: Deque[Request] = deque()
         self._backfilled: Deque[Tuple[Request, Any, int]] = deque()
-        # Cancelled mid-flight, awaiting delivery via the next step()'s
-        # ``finished`` list (keeps run()'s one-completion-per-request
-        # contract across cancellations).
-        self._cancelled: List[Request] = []
+        # Finished (or cancelled) outside a step, awaiting delivery via
+        # the next step()'s ``finished`` list (keeps run()'s
+        # one-completion-per-request contract).
+        self._finished: List[Request] = []
 
     # Subclass hooks (the paged engine swaps storage + decode step but
     # keeps the ladder/window/admission policy).
@@ -289,6 +368,9 @@ class SlotServeEngine:
             "slot_admits": 0, "slot_releases": 0,
             "preemptions": 0, "cancelled": 0,
             "remeshes": 0,
+            # Windows dispatched while another was in flight, and forced
+            # read-backs of the window in flight (``drain_pipeline``).
+            "windows_overlapped": 0, "pipeline_drains": 0,
         }
 
     def _prefill_cache_len(self) -> Optional[int]:
@@ -333,6 +415,31 @@ class SlotServeEngine:
             return tree
         return jax.lax.with_sharding_constraint(tree, fn(tree))
 
+    def _row_sharding(self):
+        """Replicated sharding of the per-slot ``toks``/``pos``/``budget``
+        rows on a mesh (None when single-device).  A window fed from the
+        host and one fed from the last window's outputs then share one
+        input sharding, and so one compiled program."""
+        if self.mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(self.mesh, P())
+
+    def _rows_to_device(self, rows: np.ndarray):
+        # A copy: the CPU backend may alias host memory, and the mirrors
+        # advance in place while the window is still queued.
+        rows = np.array(rows)
+        sharding = self._row_sharding()
+        if sharding is None:
+            return jnp.asarray(rows)
+        return jax.device_put(rows, sharding)
+
+    def _constrain_rows(self, rows):
+        sharding = self._row_sharding()
+        if sharding is None:
+            return rows
+        return jax.lax.with_sharding_constraint(rows, sharding)
+
     def reset(self) -> None:
         """Clear all serving state for a fresh serve on the same engine.
 
@@ -340,9 +447,10 @@ class SlotServeEngine:
         its device buffers, so a long-lived engine (or a fuzz harness
         running many workloads) compiles once per shape, not per serve.
         """
+        self.drain_pipeline("reset")
         self.queue.clear()
         self._backfilled.clear()
-        self._cancelled.clear()
+        self._finished.clear()
         self._req = [None] * self.max_batch
         self._tok[:] = 0
         self._pos[:] = 0
@@ -372,6 +480,7 @@ class SlotServeEngine:
         if self.mesh is None:
             raise ValueError("remesh requires a mesh-aware engine "
                              "(construct with mesh=...)")
+        self.drain_pipeline("remesh")
         victims: List[Request] = []
         for slot in range(self.max_batch):
             if self._req[slot] is not None:
@@ -414,15 +523,17 @@ class SlotServeEngine:
         T = self.window
 
         def decode_window(params, caches, toks, pos, budget, *, rung):
-            """T greedy tokens at batch shape ``rung``; one host sync.
+            """T greedy tokens at batch shape ``rung``.
 
-            toks/pos/budget: (rung,) int32 — last emitted token, next
-            write position, and remaining token budget per slot.  Rows
-            with budget <= 0 (holes, finished requests) stay frozen and
-            emit -1; their attention output is computed but discarded,
-            and their (deterministic, value-stable) cache writes land in
-            slots that are either released or fully overwritten at the
-            next admission.
+            toks/pos/budget: (max_batch,) int32 — last emitted token,
+            next write position, and remaining token budget per slot;
+            the window reads and advances the first ``rung`` rows and
+            passes the rest through, so its outputs feed the next window
+            at any rung.  Rows with budget <= 0 (holes, finished
+            requests) stay frozen and emit -1; their attention output is
+            computed but discarded, and their (deterministic,
+            value-stable) cache writes land in slots that are either
+            released or fully overwritten at the next admission.
             """
             # Executes at trace time only: a jax-version-proof compile
             # counter backing the jit-cache one (tracing == compiling
@@ -430,26 +541,18 @@ class SlotServeEngine:
             self._window_traces += 1
             sub = jax.tree.map(
                 lambda x: jax.lax.slice_in_dim(x, 0, rung, axis=1), caches)
-
-            def body(carry, _):
-                c, tk, ps, bd = carry
-                logits, c = decode_fn(params, c, tk[:, None], ps)
-                nxt = jnp.argmax(logits[:, -1, :vocab],
-                                 axis=-1).astype(jnp.int32)
-                live = bd > 0
-                emit = jnp.where(live, nxt, -1)
-                tk = jnp.where(live, nxt, tk)
-                ps = jnp.where(live, ps + 1, ps)
-                bd = jnp.where(live, bd - 1, bd)
-                bd = jnp.where(ps >= max_seq - 1, 0, bd)
-                return (c, tk, ps, bd), emit
-
-            (sub, toks, pos, budget), out = jax.lax.scan(
-                body, (sub, toks, pos, budget), None, length=T)
+            rows = (toks[:rung], pos[:rung], budget[:rung])
+            (sub, *rows), out = jax.lax.scan(
+                decode_body(lambda c, tk, ps: decode_fn(params, c, tk, ps),
+                            vocab, max_seq),
+                (sub, *rows), None, length=T)
             caches = jax.tree.map(
                 lambda full, s: jax.lax.dynamic_update_slice_in_dim(
                     full, s, 0, axis=1), caches, sub)
             caches = self._constrain_caches(caches)
+            toks, pos, budget = self._constrain_rows(tuple(
+                jax.lax.dynamic_update_slice_in_dim(full, r, 0, axis=0)
+                for full, r in zip((toks, pos, budget), rows)))
             return caches, toks, pos, budget, out
 
         donate = () if jax.default_backend() == "cpu" else (1,)
@@ -564,6 +667,9 @@ class SlotServeEngine:
         batch-class resident (:meth:`_preempt_slot`) instead of
         stalling behind the pool — ``_admit_cap`` exhaustion degrades
         gracefully rather than walling off interactive traffic.
+
+        A row joins only with no window in flight: the pipeline drains
+        as a slot is taken.
         """
         waiting = [r for r, _, _ in self._backfilled] + list(self.queue)
         n_live = self._n_active() + len(waiting)
@@ -596,6 +702,7 @@ class SlotServeEngine:
                 req = self.queue[idx]
                 del self.queue[idx]
                 cache, pos = self._prefill_one(req)
+            self.drain_pipeline("admit")
             slot = self.cache.acquire()
             self._store_cache(req, cache, slot)
             self._req[slot] = req
@@ -631,9 +738,16 @@ class SlotServeEngine:
     # ------------------------------------------------------------------
     def _preempt_for(self, head: Request) -> bool:
         """Evict one batch-class resident to unblock ``head``'s
-        admission; returns True iff a victim was preempted."""
+        admission; returns True iff a victim was preempted or the drain
+        before it made room."""
         if not self.policy.preemption:
             return False
+        if self._inflight is not None:
+            # Rows finishing in the window in flight may free the room:
+            # read it back, and evict only if they did not.
+            self.drain_pipeline("preempt")
+            if self.cache.n_free and self._can_admit(head):
+                return True
         resident = [(s, r) for s, r in enumerate(self._req)
                     if r is not None]
         victim = self.policy.choose_victim(resident)
@@ -660,6 +774,7 @@ class SlotServeEngine:
         storm): policy victim choice first, then — the policy only ever
         names batch-class victims — any remaining resident by lowest
         progress.  Returns the number actually preempted."""
+        self.drain_pipeline("preempt")
         count = 0
         for _ in range(n):
             resident = [(s, r) for s, r in enumerate(self._req)
@@ -679,7 +794,11 @@ class SlotServeEngine:
         slot (and, on the paged engine, its pages) immediately — a
         waiting admission can proceed this very step; queued/backfilled
         entries are dropped.  Marks the request done with
-        ``finish_reason="cancelled"``; returns True iff found."""
+        ``finish_reason="cancelled"``; returns True iff found (a
+        resident that the drain before its release finishes is done, not
+        found)."""
+        if any(r is not None and r.rid == rid for r in self._req):
+            self.drain_pipeline("cancel")
         for slot, req in enumerate(self._req):
             if req is not None and req.rid == rid:
                 self._req[slot] = None
@@ -703,13 +822,17 @@ class SlotServeEngine:
         req.done = True
         req.finish_reason = FINISH_CANCELLED
         req.finished_at = now()
-        self._cancelled.append(req)
+        self._finished.append(req)
         self.stats["engine"]["cancelled"] += 1
         return True
 
     def _current_rung(self) -> int:
+        """Smallest ladder rung covering the highest row with budget
+        left (0 when none has): rows that finish in the window in flight
+        are not decoded again."""
         highest = max((i + 1 for i, r in enumerate(self._req)
-                       if r is not None), default=0)
+                       if r is not None and self._budget[i] > 0),
+                      default=0)
         if highest == 0:
             return 0
         return next(r for r in self.rungs if r >= highest)
@@ -732,42 +855,86 @@ class SlotServeEngine:
             self.params, self.cache.buffers, toks, pos, budget, rung=rung)
         return toks, pos, budget, out
 
-    def _run_window(self, rung: int, finished: List[Request]) -> None:
-        with span("serve.window.prep", rung=rung):
+    def _dispatch(self, rung: int) -> Optional[_Window]:
+        """Dispatch the next window at ``rung`` and return the one it
+        overlaps (still in flight, not yet read back), if any.
+
+        With a window in flight the new one takes that window's device
+        outputs as its inputs (no host copy, no wait); otherwise the
+        host mirrors, which are exact then.  The mirrors advance here,
+        at dispatch, so the next window's ``_map_window`` sees where this
+        one leaves the rows before it is read back."""
+        prev = self._inflight
+        idx = self.stats["engine"]["windows"]
+        with span("serve.window.prep", rung=rung, window=idx):
             self._map_window(rung)
-            toks = jnp.asarray(self._tok[:rung])
-            pos = jnp.asarray(self._pos[:rung])
-            budget = jnp.asarray(self._budget[:rung])
-        with span("serve.window.dispatch", rung=rung):
-            toks, pos, budget, out = self._window_call(rung, toks, pos, budget)
+            if prev is None:
+                toks, pos, budget = (self._rows_to_device(a) for a in
+                                     (self._tok, self._pos, self._budget))
+            else:
+                toks, pos, budget = prev.toks, prev.pos, prev.budget
+        with span("serve.window.dispatch", rung=rung, window=idx):
+            toks, pos, budget, out = self._window_call(rung, toks, pos,
+                                                       budget)
+        advance_rows(self._pos[:rung], self._budget[:rung], self.window,
+                     self.max_seq)
+        self._inflight = _Window(idx, rung, toks, pos, budget, out,
+                                 self._pos.copy(), self._budget.copy())
         entries = jit_cache_entries(self._window_fn)
         raw = entries if entries is not None else self._window_traces
         self.stats["decode_compiles"] = max(0, raw - self._compile_base)
-        self.stats["engine"]["windows"] += 1
-        self.stats["engine"]["rungs"].append(rung)
+        ext = self.stats["engine"]
+        ext["windows"] += 1
+        ext["rungs"].append(rung)
+        ext["windows_overlapped"] += prev is not None
         self.stats["decode_steps"] += self.window
-        # The single host sync of the window:
-        with span("serve.window.sync", rung=rung):
-            out_np = np.asarray(out)                     # (T, rung)
-        with span("serve.window.post", rung=rung) as sp:
-            self._tok[:rung] = np.asarray(toks)
-            self._pos[:rung] = np.asarray(pos)
-            self._budget[:rung] = np.asarray(budget)
-            n_done = len(finished)
-            for slot in range(rung):
+        return prev
+
+    def _read_back(self, win: _Window) -> None:
+        """Read a window's tokens back and post them: extend each row's
+        stream, and finish and release the rows whose budget it spent.
+        Windows are read back in dispatch order."""
+        with span("serve.window.sync", rung=win.rung, window=win.idx):
+            out, toks, pos, budget = jax.device_get(
+                (win.out, win.toks, win.pos, win.budget))   # (T, rung), ...
+        with span("serve.window.post", rung=win.rung, window=win.idx) as sp:
+            if not (np.array_equal(pos, win.pos_after)
+                    and np.array_equal(budget, win.budget_after)):
+                raise AssertionError(
+                    f"window {win.idx} left pos/budget {pos}/{budget}, "
+                    f"the host mirrors {win.pos_after}/{win.budget_after}")
+            self._tok[:] = toks
+            n_done = 0
+            for slot in range(win.rung):
                 req = self._req[slot]
                 if req is None:
                     continue
-                col = out_np[:, slot]
+                col = out[:, slot]
                 req.generated.extend(int(t) for t in col[col >= 0])
-                if self._budget[slot] <= 0:
+                if win.budget_after[slot] <= 0:
                     req.done = True
                     req.finished_at = now()
-                    finished.append(req)
+                    self._finished.append(req)
                     self._req[slot] = None
                     self._release_slot(slot)
                     self.stats["engine"]["slot_releases"] += 1
-            sp.set_metadata(finished=len(finished) - n_done)
+                    n_done += 1
+            sp.set_metadata(finished=n_done)
+
+    def drain_pipeline(self, reason: str) -> None:
+        """Read back the window in flight, if any, so that the row set
+        and storage can change: the next window then starts from the
+        host mirrors.  ``reason`` names the cause on the
+        ``serve.window.drain`` span (``admit``, ``preempt``, ``cancel``,
+        ``remesh``, ``reset``, ``fault``, ``idle`` — nothing left to
+        decode — or ``shutdown``)."""
+        win, self._inflight = self._inflight, None
+        if win is None:
+            return
+        with span("serve.window.drain", reason=reason, rung=win.rung,
+                  window=win.idx):
+            self._read_back(win)
+        self.stats["engine"]["pipeline_drains"] += 1
 
     def _plan_step(self) -> int:
         """Multi-tenant co-schedule of this window (stats + backfill
@@ -783,20 +950,21 @@ class SlotServeEngine:
                                    self.cfg, bool(self.coexec_backend))
 
     def step(self, finished: List[Request], max_steps: int = 512) -> int:
-        """One scheduler iteration at a window boundary: admit up to the
-        ladder target, run one decode window, then execute co-scheduled
-        prefills in the sync gap.  Appends newly finished requests to
-        ``finished`` and returns the decode steps consumed (0 when
-        idle).  This is the granularity the online frontend drives —
-        between two calls the engine state is at a window boundary, so
-        the frontend can inject batched prefills and read fresh tokens.
+        """One scheduler iteration: admit up to the ladder target,
+        dispatch the next decode window, read back the one before it,
+        then execute co-scheduled prefills.  Appends newly finished
+        requests to ``finished`` and returns the decode steps dispatched
+        (1 for a step that only read back or found nothing to decode; 0
+        when idle).  This is the granularity the online frontend drives:
+        between two calls at most one window is in flight, every token
+        of the windows before it is on the host, and the frontend can
+        inject batched prefills and read fresh tokens.
         """
         with span("serve.step") as sp:
-            if self._cancelled:
-                finished.extend(self._cancelled)
-                self._cancelled.clear()
-            if not (self.queue or self._backfilled or self._n_active()) \
-                    or max_steps <= 0:
+            if not (self.queue or self._backfilled or self._n_active()
+                    or self._inflight) or max_steps <= 0:
+                finished.extend(self._finished)
+                self._finished.clear()
                 sp.set_metadata(rung=0)
                 return 0
             with span("serve.admit") as admit:
@@ -811,16 +979,22 @@ class SlotServeEngine:
                 to_backfill = [self.queue.popleft() for _ in range(nb)]
             rung = self._current_rung()
             sp.set_metadata(rung=rung)
+            consumed = 1
             if rung:
-                self._run_window(rung, finished)
+                prev = self._dispatch(rung)
+                if prev is not None:
+                    self._read_back(prev)
                 consumed = self.window
             else:
-                consumed = 1
+                self.drain_pipeline("idle")
             # Co-scheduled prefills run at the window boundary (the
             # fused grid interleaves them with the decode window on the
-            # array; at the host level they fill the sync gap).
+            # array; at the host level they overlap the window in
+            # flight).
             for r in to_backfill:
                 self._backfill_one(r)
+            finished.extend(self._finished)
+            self._finished.clear()
             return consumed
 
     def run(self, max_steps: int = 512) -> List[Completion]:
@@ -834,8 +1008,9 @@ class SlotServeEngine:
         while ((self.queue or self._backfilled or self._n_active())
                and max_steps > 0):
             max_steps -= self.step(finished, max_steps)
-        finished.extend(self._cancelled)   # cancelled with no step after
-        self._cancelled.clear()
+        self.drain_pipeline("idle")        # the window left in flight
+        finished.extend(self._finished)    # and rows cancelled after it
+        self._finished.clear()
         return [completion_of(r) for r in finished]
 
     # ------------------------------------------------------------------
@@ -953,12 +1128,16 @@ class SlotServeEngine:
                     self._prefill_group(group, b)
             self._backfilled.clear()
         self._warm_storage()
+        zeros = np.zeros(self.max_batch, np.int32)
         for rung in warm_rungs:
             # Budget-0 rows are frozen: the window computes and discards
             # their logits, and released rows only write the sink/own
             # slot, so warmup mutates no live state besides storage.
-            zeros = jnp.zeros(rung, jnp.int32)
-            self._window_call(rung, zeros, zeros, zeros)
+            # Fed from the host, then from its own outputs, as the
+            # pipeline feeds it.
+            rows = [self._rows_to_device(zeros) for _ in range(3)]
+            rows = self._window_call(rung, *rows)[:3]
+            jax.block_until_ready(self._window_call(rung, *rows))
         self.reset()
         entries = jit_cache_entries(self._window_fn)
         self._compile_base = (entries if entries is not None

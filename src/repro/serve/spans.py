@@ -20,14 +20,18 @@ Spans, by thread (stats in brackets):
   (``cycle``); ``serve.intake``, admission of arrivals (``admitted``,
   ``deferred``); ``serve.prefill``, one prefill call up to its first
   tokens on the host (``rids``, ``rows``, ``bucket``); ``serve.step``,
-  one engine step (``rung``; 0 when no window ran); ``serve.admit``,
-  slot admission: ladder target, cache store, page admission
-  (``admitted``); ``serve.window.prep``, page mapping and the
-  host-to-device copies of the window's inputs (``rung``);
-  ``serve.window.dispatch``, the jitted window call (``rung``);
-  ``serve.window.sync``, the wait for the window's tokens (``rung``);
-  ``serve.window.post``, the other device-to-host copies, per-slot
-  bookkeeping and releases (``rung``, ``finished``); ``serve.emit``,
+  one engine step (``rung`` of the window it dispatched; 0 when none);
+  ``serve.admit``, slot admission: ladder target, cache store, page
+  admission (``admitted``); ``serve.window.prep``, page mapping and,
+  with no window in flight, the host-to-device copies of the window's
+  inputs (``rung``, ``window``: its index since the last reset);
+  ``serve.window.dispatch``, the jitted window call (``rung``,
+  ``window``); ``serve.window.sync``, the wait for a window's tokens —
+  in a step, the window dispatched before the step's own (``rung``,
+  ``window``); ``serve.window.post``, per-slot bookkeeping and releases
+  (``rung``, ``window``, ``finished``); ``serve.window.drain``, a
+  forced read-back of the window in flight, holding its sync and post
+  (``reason``, ``rung``, ``window``); ``serve.emit``,
   new tokens onto the backlog (``tokens``); ``serve.idle``, the wait
   for work when nothing moved.
 - emit: ``serve.deliver``, one backlog item delivered, callbacks

@@ -345,6 +345,77 @@ class TestWarmServing:
         assert stats["decode_compiles"] == 0
 
 
+def _wait_tokens(handle, n, timeout=60.0):
+    t0 = time.time()
+    while len(handle.tokens) < n:
+        assert time.time() - t0 < timeout, (handle.rid, handle.tokens)
+        time.sleep(0.002)
+
+
+class TestWindowPipeline:
+    """The scheduler over the engine's two-deep window pipeline, counted
+    in engine windows, not time, at one decode step per window (the
+    default on an accelerator)."""
+
+    def test_an_arrival_prefills_within_two_windows(self, setup):
+        """A request submitted while a window is in flight has its
+        prefill dispatched before at most two further decode windows,
+        and its stream is the offline one."""
+        cfg, params = setup
+        prompts = _workload(cfg, [9, 6], seed=21)
+        ref = make_engine(cfg, params, kind="paged", max_slots=MAX_SLOTS,
+                          max_seq=256, window=8)
+        for i, (p, n) in enumerate(zip(prompts, [120, 8])):
+            ref.submit(Request(rid=i, prompt=p, max_new_tokens=n))
+        want = {c.rid: c.tokens for c in ref.run(max_steps=4096)}
+        eng = make_engine(cfg, params, kind="paged", max_slots=MAX_SLOTS,
+                          max_seq=256, window=1)
+        at_prefill = []
+        prefill = eng.prefill_batch
+
+        def counted(reqs):
+            at_prefill.append(eng.stats["decode_steps"])
+            prefill(reqs)
+
+        eng.prefill_batch = counted
+        fe = ServeFrontend(eng)
+        a = fe.submit(prompts[0], 120)
+        _wait_tokens(a, 5)
+        assert eng._inflight is not None       # a window is in flight
+        submitted_at = eng.stats["decode_steps"]
+        b = fe.submit(prompts[1], 8)
+        done = {c.rid: c.tokens for c in fe.drain(timeout=120)}
+        fe.shutdown()
+        assert done == want
+        assert len(at_prefill) == 2
+        assert at_prefill[1] - submitted_at <= 2
+        assert b.admitted_at is not None
+
+    def test_overlap_and_drain_counters_on_a_scripted_run(self, setup):
+        """A joins an idle engine (nothing to drain), B joins it running
+        (one drain), A is cancelled (one drain), B runs dry (one drain):
+        three drains, and every other window overlapped the one before."""
+        cfg, params = setup
+        prompts = _workload(cfg, [9, 6], seed=22)
+        eng = make_engine(cfg, params, kind="paged", max_slots=MAX_SLOTS,
+                          max_seq=256, window=1)
+        fe = ServeFrontend(eng)
+        a = fe.submit(prompts[0], 200)
+        _wait_tokens(a, 3)
+        b = fe.submit(prompts[1], 30)
+        _wait_tokens(b, 3)
+        assert a.cancel()
+        assert a.result(timeout=60).finish_reason == "cancelled"
+        assert b.result(timeout=60).finish_reason == "length"
+        stats = fe.stats
+        fe.shutdown()
+        ext = stats["engine"]
+        assert (ext["slot_admits"], ext["cancelled"]) == (2, 1)
+        assert ext["pipeline_drains"] == 3
+        assert ext["windows_overlapped"] == ext["windows"] - 3
+        assert ext["windows_overlapped"] > 0.8 * ext["windows"]
+
+
 class TestBatchedPrefillIdentity:
     def test_batched_rows_bitwise_equal_single(self, setup):
         """The coalesced multi-prompt prefill is bitwise the

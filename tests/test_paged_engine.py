@@ -364,6 +364,84 @@ def _run(engine, prompts, budgets, max_steps=2000):
     return {c.rid: c.tokens for c in done}
 
 
+class TestPipelinePageReuse:
+    def test_freed_page_reused_by_the_next_admission(self, setup):
+        """Row A finishes in window j and rides window j+1 frozen, its
+        last write landing in its own page; j's read-back frees that
+        page while j+1 is in flight, and the very next admission (B,
+        blocked on the pool until then) drains j+1 and scatters its
+        prompt into the page.  Every stream equals the sequential
+        engine's: the drain orders B's writes after A's frozen one."""
+        cfg, params = setup
+        pa, pc, pb = _prompts([7, 6, 9], cfg.vocab_size, seed=8)
+        budgets = {0: 5, 1: 30, 2: 6}
+        seq = make_engine(cfg, params, kind="sequential", max_slots=1,
+                          max_seq=56)
+        want = _run(seq, [pa, pc, pb], [budgets[i] for i in range(3)])
+        # A reserves 2 pages, C 5: B's 2 fit only in A's, once freed.
+        eng = make_engine(cfg, params, kind="paged", max_slots=3,
+                          max_seq=56, page_size=8, num_pages=7, window=1)
+        eng.submit(Request(rid=0, prompt=pa, max_new_tokens=budgets[0]))
+        eng.submit(Request(rid=1, prompt=pc, max_new_tokens=budgets[1]))
+        fin, a_pages, b_at = [], [], None
+        for step in range(200):
+            if step == 1:
+                eng.submit(Request(rid=2, prompt=pb,
+                                   max_new_tokens=budgets[2]))
+                assert not eng._can_admit(eng.queue[0])   # truly blocked
+            if eng._req[0] is not None and eng._req[0].rid == 0:
+                a_pages = eng.cache.mapped_pages(0)
+            in_flight = eng._inflight is not None
+            drains = eng.stats["engine"]["pipeline_drains"]
+            if not eng.step(fin):
+                break
+            if b_at is None and any(r is not None and r.rid == 2
+                                    for r in eng._req):
+                b_at = step
+                slot = next(s for s, r in enumerate(eng._req)
+                            if r is not None and r.rid == 2)
+                assert in_flight
+                assert eng.stats["engine"]["pipeline_drains"] == drains + 1
+                assert set(a_pages) & set(eng.cache.mapped_pages(slot))
+        assert b_at is not None and len(a_pages) == 2
+        assert {r.rid: tuple(r.generated) for r in fin} == want
+        assert eng.cache.n_free_pages == eng.cache.num_pages
+
+    def test_no_eviction_when_the_drain_frees_the_room(self, setup):
+        """An interactive arrival blocked on the pool while the window in
+        flight holds a batch row's last step: the preemption path drains
+        first, the row's release makes the room, and nobody is evicted.
+        Every stream equals the sequential engine's."""
+        cfg, params = setup
+        pa, pc, pb = _prompts([7, 6, 9], cfg.vocab_size, seed=8)
+        budgets = {0: 5, 1: 30, 2: 6}
+        seq = make_engine(cfg, params, kind="sequential", max_slots=1,
+                          max_seq=56)
+        want = _run(seq, [pa, pc, pb], [budgets[i] for i in range(3)])
+        eng = make_engine(cfg, params, kind="paged", max_slots=3,
+                          max_seq=56, page_size=8, num_pages=7, window=1)
+        eng.submit(Request(rid=0, prompt=pa, max_new_tokens=budgets[0]))
+        eng.submit(Request(rid=1, prompt=pc, max_new_tokens=budgets[1]))
+        fin = []
+        for _ in range(50):          # until A's last step is in flight
+            eng.step(fin)
+            if eng._budget[0] == 0:
+                break
+        assert eng._inflight is not None and eng._req[0].rid == 0
+        eng.submit(Request(rid=2, prompt=pb, max_new_tokens=budgets[2],
+                           klass="interactive"))
+        assert not eng._can_admit(eng.queue[0])       # truly blocked
+        drains = eng.stats["engine"]["pipeline_drains"]
+        eng.step(fin)
+        assert eng._req[0] is not None and eng._req[0].rid == 2
+        assert eng.stats["engine"]["pipeline_drains"] == drains + 1
+        for _ in range(200):
+            if not eng.step(fin):
+                break
+        assert eng.stats["engine"]["preemptions"] == 0
+        assert {r.rid: tuple(r.generated) for r in fin} == want
+
+
 class TestPagedCompileStability:
     def test_one_compile_per_rung_with_page_growth(self, setup):
         """>=3 rungs in one serve *and* budgets long enough that rows

@@ -6,7 +6,9 @@ A smoke serve through ``ServeFrontend`` over the paged engine runs under
 ``ProfileData``: every span of the table appears, the window's spans
 nest in an engine step inside a scheduler cycle, and the ``rid``/
 ``rids`` stats tie each request's submit, prefill and delivery together
-across threads.
+across threads.  The engine keeps one window in flight: a step
+dispatches its window and reads back the one before, and a drain reads
+back the window in flight, with its reason.
 """
 import time
 from pathlib import Path
@@ -22,7 +24,8 @@ from repro.serve import make_engine, ServeFrontend
 SPANS = ("serve.submit", "serve.submit.lock", "serve.cycle",
          "serve.intake", "serve.prefill", "serve.step", "serve.admit",
          "serve.window.prep", "serve.window.dispatch", "serve.window.sync",
-         "serve.window.post", "serve.emit", "serve.idle", "serve.deliver")
+         "serve.window.post", "serve.window.drain", "serve.emit",
+         "serve.idle", "serve.deliver")
 WINDOW_SPANS = [s for s in SPANS if s.startswith("serve.window.")]
 
 
@@ -90,13 +93,48 @@ def test_window_spans_nest_in_a_step_inside_a_cycle(served):
 
     windows = [e for e in events if e[0] in WINDOW_SPANS]
     assert windows
+    rung_of = {e[4]["window"]: e[4]["rung"] for e in windows
+               if e[0] == "serve.window.dispatch"}
     for ev in windows:
         steps = holder(ev, "serve.step")
         assert len(steps) == 1, ev
-        assert steps[0][4]["rung"] == ev[4]["rung"] > 0
         assert len(holder(steps[0], "serve.cycle")) == 1, ev
+        # Each span names its window; a step dispatches its own window
+        # at the step's rung and reads back an earlier one at that one's.
+        assert ev[4]["rung"] == rung_of[ev[4]["window"]] > 0, ev
+        if ev[0] in ("serve.window.prep", "serve.window.dispatch"):
+            assert steps[0][4]["rung"] == ev[4]["rung"], ev
     assert all(e[4]["finished"] >= 0 for e in windows
                if e[0] == "serve.window.post")
+    # Every window dispatched is read back once, after its dispatch.
+    start = {e[4]["window"]: e[1] for e in windows
+             if e[0] == "serve.window.dispatch"}
+    for name in ("serve.window.sync", "serve.window.post"):
+        read = [e for e in windows if e[0] == name]
+        assert sorted(e[4]["window"] for e in read) == sorted(start)
+        assert all(e[1] > start[e[4]["window"]] for e in read)
+
+
+def test_drains_name_their_reason_and_hold_the_read_back(served):
+    """A drain reads back the window in flight inside its own span: at
+    least the one at the end of each burst (nothing left to decode), and
+    no reason outside the engine's list.  The counters agree with the
+    trace: every window dispatched with nothing in flight ends in one
+    drain."""
+    _, _, stats, events = served
+    drains = [e for e in events if e[0] == "serve.window.drain"]
+    reasons = {d[4]["reason"] for d in drains}
+    assert "idle" in reasons
+    assert reasons <= {"admit", "preempt", "cancel", "remesh", "reset",
+                       "fault", "idle", "shutdown"}
+    for d in drains:
+        held = [e for e in events if e[0] in ("serve.window.sync",
+                                              "serve.window.post")
+                and e[3] == d[3] and d[1] <= e[1] and e[2] <= d[2]]
+        assert [e[4]["window"] for e in held] == [d[4]["window"]] * 2, d
+    ext = stats["engine"]
+    assert ext["pipeline_drains"] == len(drains)
+    assert ext["windows"] - ext["windows_overlapped"] == len(drains)
 
 
 def test_rid_stats_link_each_request_across_threads(served):

@@ -21,6 +21,7 @@ import pytest
 from repro.configs import get_config, smoke_config
 from repro.models import init_params
 from repro.serve import make_engine, Request
+from repro.serve.policy import SchedulingPolicy
 
 
 @pytest.fixture(scope="module")
@@ -240,3 +241,158 @@ class TestWindowedPromptBuckets:
         assert ext["prefill_bucket_misses"] == 1
         assert ext["prefill_bucket_hits"] == 1
         assert ext["prefill_bucket_fallbacks"] == 2
+
+
+def _script(eng, arrivals, actions=None, max_steps=400):
+    """Drive ``eng.step()`` by hand: ``arrivals`` maps a step number to
+    the requests submitted before it, ``actions`` to a call on the
+    engine made before it.  Returns the finished requests by rid."""
+    fin, pending, actions = [], dict(arrivals), dict(actions or {})
+    for step in range(max_steps):
+        for req in pending.pop(step, []):
+            eng.submit(req)
+        if step in actions:
+            actions.pop(step)(eng)
+        if not eng.step(fin) and not pending and not actions:
+            break
+    return {r.rid: r for r in fin}
+
+
+# name -> (max_seq, [(arrival step, prompt len, budget)], {step: action})
+PIPELINE_CASES = {
+    # Rows join a running engine: each admission drains the window in
+    # flight, and decode goes on overlapped.
+    "admitted_midstream": (64, [(0, 6, 20), (3, 9, 6), (5, 5, 8)], {}),
+    # Staggered budgets: every row finishes in a window whose successor
+    # is already in flight, and rides it frozen.
+    "finishing_in_flight": (64, [(0, 7, 3), (0, 5, 5), (0, 9, 7),
+                                 (0, 6, 9)], {}),
+    "preempt_resume": (64, [(0, 9, 14), (0, 17, 12), (0, 6, 20)],
+                       {1: lambda e: e.preempt(1), 2: lambda e: e.preempt(2)}),
+    "cancel": (64, [(0, 9, 12), (0, 5, 20), (1, 7, 6)],
+               {1: lambda e: e.cancel(1)}),
+    # The prompt leaves room for fewer tokens than its budget: the row
+    # stops at max_seq - 1, on the host's mirror as on the device.
+    "max_seq": (32, [(0, 26, 20), (0, 7, 30), (2, 5, 4)], {}),
+}
+
+
+@pytest.fixture(scope="module")
+def pipeline_refs(setup):
+    """Each case's requests served one at a time by the sequential
+    engine: no window, no pipeline, no batch."""
+    cfg, params = setup
+    refs = {}
+    for name, (max_seq, reqs, _acts) in PIPELINE_CASES.items():
+        prompts = _prompts([n for _, n, _ in reqs], cfg.vocab_size, seed=9)
+        seq = make_engine(cfg, params, kind="sequential", max_slots=1,
+                          max_seq=max_seq)
+        refs[name] = _run(seq, prompts, [b for _, _, b in reqs])
+    return refs
+
+
+class _TargetOne(SchedulingPolicy):
+    """The default policy with a ladder target of one row."""
+
+    def ladder_target(self, *args, **kwargs) -> int:
+        return 1
+
+
+class TestWindowPipeline:
+    """The two-deep window pipeline (one window queued on the device
+    while the host reads the last one back) emits the tokens of the
+    sequential engine in every case that changes the row set while a
+    window is in flight, on both slot engine kinds; window 8 is a second
+    window length through the same pipeline."""
+
+    @pytest.mark.parametrize("case", sorted(PIPELINE_CASES))
+    @pytest.mark.parametrize("kind", ["slot", "paged"])
+    @pytest.mark.parametrize("window", [1, 8])
+    def test_tokens_match_the_sequential_engine(self, setup, pipeline_refs,
+                                                kind, case, window):
+        cfg, params = setup
+        max_seq, reqs, actions = PIPELINE_CASES[case]
+        prompts = _prompts([n for _, n, _ in reqs], cfg.vocab_size, seed=9)
+        kw = {"page_size": 8} if kind == "paged" else {}
+        eng = make_engine(cfg, params, kind=kind, max_slots=4,
+                          max_seq=max_seq, window=window, **kw)
+        arrivals = {}
+        for rid, ((at, _n, budget), p) in enumerate(zip(reqs, prompts)):
+            arrivals.setdefault(at, []).append(
+                Request(rid=rid, prompt=p, max_new_tokens=budget))
+        got = _script(eng, arrivals, actions)
+        want = pipeline_refs[case]
+        assert sorted(got) == sorted(want)
+        for rid, req in got.items():
+            if req.finish_reason == "cancelled":
+                assert 1 <= len(req.generated) < len(want[rid])
+                assert tuple(req.generated) == want[rid][:len(req.generated)]
+            else:
+                assert tuple(req.generated) == want[rid], rid
+        ext = eng.stats["engine"]
+        assert eng._inflight is None and eng._n_active() == 0
+        # Every window dispatched with none in flight ends in one drain.
+        assert ext["windows"] - ext["windows_overlapped"] \
+            == ext["pipeline_drains"] >= 1
+        if window == 1:
+            assert ext["windows_overlapped"] >= 1
+        assert ext["slot_admits"] == ext["slot_releases"] \
+            == len(reqs) + ext["preemptions"]
+        if case == "preempt_resume":
+            assert ext["preemptions"] >= 2
+        if case == "cancel":
+            assert ext["cancelled"] == 1
+        if case == "max_seq":
+            assert len(got[0].generated) == max_seq - 26 < 20
+
+    def test_a_finishing_row_keeps_its_slot_until_read_back(self, setup):
+        """A row whose budget runs out in window j is frozen (budget 0)
+        in window j+1 and keeps its slot until j is read back; a drain
+        then reads back j+1 and the mirrors match the device."""
+        cfg, params = setup
+        p0, p1 = _prompts([6, 8], cfg.vocab_size, seed=4)
+        eng = make_engine(cfg, params, kind="slot", max_slots=2,
+                          max_seq=64, window=1)
+        eng.submit(Request(rid=0, prompt=p0, max_new_tokens=3))
+        eng.submit(Request(rid=1, prompt=p1, max_new_tokens=9))
+        fin = []
+        eng.step(fin)        # window 0 dispatched; nothing read back
+        assert eng._inflight.idx == 0 and not fin
+        eng.step(fin)        # window 1 in flight, window 0 read back
+        assert eng._inflight.idx == 1 and eng._budget[0] == 0
+        assert eng._req[0] is not None and eng._req[0].rid == 0
+        eng.step(fin)        # rid 0's last token comes with window 1
+        assert [r.rid for r in fin] == [0] and eng._req[0] is None
+        eng.drain_pipeline("test")     # reads window 2 back: mirrors hold
+        assert eng._inflight is None
+        assert eng.stats["engine"]["pipeline_drains"] == 1
+        assert (eng._pos[1], eng._budget[1]) == (8 + 3, 8 - 3)
+        assert len(eng._req[1].generated) == 1 + 3
+
+    def test_a_row_held_back_by_the_ladder_target_drains_nothing(self,
+                                                                 setup):
+        """A waiting row that admission would not take (the ladder
+        target is reached) leaves the window in flight alone: decode
+        goes on overlapped until a row finishes and the waiting one
+        joins an idle engine."""
+        cfg, params = setup
+        p0, p1 = _prompts([6, 8], cfg.vocab_size, seed=5)
+        seq = make_engine(cfg, params, kind="sequential", max_slots=1,
+                          max_seq=64)
+        want = _run(seq, [p0, p1], [12, 6])
+        eng = make_engine(cfg, params, kind="slot", max_slots=4,
+                          max_seq=64, window=1, policy=_TargetOne())
+        eng.submit(Request(rid=0, prompt=p0, max_new_tokens=12))
+        eng.submit(Request(rid=1, prompt=p1, max_new_tokens=6))
+        fin = []
+        eng.step(fin)
+        assert eng._n_active() == 1 and len(eng.queue) == 1
+        for _ in range(8):
+            eng.step(fin)
+        ext = eng.stats["engine"]
+        assert ext["pipeline_drains"] == 0 and len(eng.queue) == 1
+        assert ext["windows_overlapped"] == ext["windows"] - 1 == 8
+        got = _script(eng, {})
+        got.update({r.rid: r for r in fin})
+        assert {rid: tuple(r.generated) for rid, r in got.items()} == want
+
